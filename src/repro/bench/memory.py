@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.bench.record import entry
-from repro.compat import saved_residual_nbytes
+from repro.core.checkpoint import saved_residual_nbytes
 from repro.configs import get_config
 from repro.configs.base import InputShape
 from repro.core import checkpoint as CK
@@ -88,7 +88,6 @@ def _dense_ep_sublayer(x, p, cfg, mesh):
     numbers are gated against the formulation they displaced."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core import routing
     from repro.core.moe_layer import _silu
     B, S, d = x.shape
@@ -113,8 +112,9 @@ def _dense_ep_sublayer(x, p, cfg, mesh):
         y = jnp.einsum("le,led->ld", cw_loc.astype(p_out.dtype), p_out)
         return jax.lax.psum(y, "model").reshape(B, S, d)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(None, None, None), p_specs),
-                     out_specs=P(None, None, None), check=False)(x, p)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(None, None, None), p_specs),
+                         out_specs=P(None, None, None), check_vma=False)(x, p)
 
 
 def ep_saved_residual_entries(*, small: bool = False) -> list:

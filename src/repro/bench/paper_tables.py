@@ -4,7 +4,7 @@ for conf1..conf7 x {SiLU, SwiGLU}.
 
 Activation memory is measured two ways, both at the paper's FULL tensor
 sizes (no execution needed):
-  * saved-residual bytes via ``compat.saved_residuals`` (the JAX analogue of
+  * saved-residual bytes via ``checkpoint.saved_residuals`` (the JAX analogue of
     the paper's PyTorch saved-tensor hooks), parameters excluded;
   * XLA ``temp_size_in_bytes`` of the compiled fwd+bwd step (corroboration).
 
@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.bench.timing import median_time_us
-from repro.compat import saved_residual_nbytes
+from repro.core.checkpoint import saved_residual_nbytes
 from repro.configs.paper_tables import PAPER_TABLE1
 from repro.core.baseline import moe_ffn_megablocks
 from repro.core.moe_layer import moe_ffn_blaze

@@ -112,7 +112,7 @@ def gmm_backend_entries(S=2048, d=256, h=512, E=8, iters=5, *,
 
     out = []
     meta = {"S": S, "d": d, "h": h, "E": E}
-    for name in GB.available_backends():
+    for name in GB.backend_names():
         if name == "pallas" and not include_pallas:
             continue
 
@@ -146,7 +146,7 @@ def fused_path_entries(L=128, d=64, h=128, E=8, k=2, iters=3) -> list:
     drifts) but load-bearing against *each other*:
     :func:`fused_gate_failures` pairs them in the same run — same machine,
     same interpreter — exactly like the memory suite's sim-parity gate."""
-    from repro import compat
+    from repro.core.checkpoint import saved_residuals
     from repro.core.moe_layer import moe_ffn_blaze
     from repro.core.routing import build_dispatch, top_k_gating
     from repro.kernels.ops import moe_ffn_blaze_pallas
@@ -175,7 +175,7 @@ def fused_path_entries(L=128, d=64, h=128, E=8, k=2, iters=3) -> list:
 
     def slot_buffers(label):
         n, nbytes = 0, 0
-        for aval, src in compat.saved_residuals(
+        for aval, src in saved_residuals(
                 layer(label), x, w1, w2, w3, gates):
             if "from the argument" in str(src):
                 continue

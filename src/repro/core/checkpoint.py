@@ -747,3 +747,29 @@ POLICY_TAGS = {n: p.saved for n, p in PLAN_REGISTRY.items() if not p.special}
 
 #: jax.checkpoint policy objects per registry name (deprecated alias).
 POLICIES = {n: _flat_policy(p) for n, p in PLAN_REGISTRY.items()}
+
+
+def saved_residuals(f, *args, **kwargs):
+    """Every ``(aval, source)`` pair autodiff saves for the backward of
+    ``f`` — the JAX analogue of PyTorch saved-tensor hooks (JAX keeps it in
+    its private ``ad_checkpoint`` module)."""
+    from jax._src.ad_checkpoint import saved_residuals as _sr
+    return _sr(f, *args, **kwargs)
+
+
+def saved_residual_nbytes(f, *args, **kwargs) -> int:
+    """Total bytes of the *activation* residuals autodiff saves for ``f``:
+    arguments/parameters excluded, as in the paper's saved-tensor accounting.
+
+    The argument filter keys on the source description string, whose wording
+    is a JAX internal — keep the heuristic in this one place.
+    """
+    import math
+    total = 0
+    for aval, src in saved_residuals(f, *args, **kwargs):
+        if not hasattr(aval, "shape"):
+            continue
+        if "from the argument" in str(src):
+            continue
+        total += math.prod(aval.shape) * aval.dtype.itemsize
+    return total

@@ -8,20 +8,18 @@ Every grouped GEMM in the MoEBlaze core funnels through two primitives:
   * ``gmm_dw(lhs, dout, group_sizes)``— (S, d), (S, h) -> (E, d, h), the
     per-group weight gradient (contract the grouped row axis).
 
-Both accumulate in fp32 and return ``lhs.dtype``.  The paper's fast path is
-``jax.lax.ragged_dot[_general]``, but those symbols only exist on newer JAX —
-this registry makes the primitive swappable per target (MegaBlocks-style)
-instead of a hard import:
+Both accumulate in fp32 and return ``lhs.dtype``.  The registry makes the
+primitive swappable (MegaBlocks-style):
 
-  * ``ragged``  — ``jax.lax.ragged_dot`` / ``ragged_dot_general``.  The XLA
-    fast path; auto-disabled when either symbol is absent (e.g. JAX 0.4.37
-    ships ``ragged_dot`` but not ``ragged_dot_general``).
-  * ``segment`` — portable pure-``jnp`` fallback: per-group row mask + dense
-    dot with fp32 accumulation.  Runs on any JAX >= 0.4.x, any device.
-    Compute is O(E·S·d·h) like XLA's own CPU decomposition of ragged_dot;
-    it exists for correctness/portability, not speed.
-  * ``pallas``  — the ``kernels/gather_gmm.py`` work-item kernels (identity
-    gather; ``interpret=True`` on CPU, real lowering on TPU).
+  * ``ragged``  — ``jax.lax.ragged_dot`` / ``ragged_dot_general``, the XLA
+    grouped GEMM (on a TPU, XLA's own grouped-matmul kernel).  The auto
+    choice.
+  * ``segment`` — pure-``jnp`` rendering: per-group row mask + dense dot
+    with fp32 accumulation.  Compute is O(E·S·d·h); it is the exact oracle
+    the parity tests compare every other backend against, not a fast path.
+  * ``pallas``  — the ``kernels/gather_gmm.py`` work-item kernels (rows
+    already in expert order), compiled by Mosaic on a TPU and interpreted on
+    the CPU (``repro.kernels.interpret_mode``).
   * ``pallas_fused`` — same kernels as a backend, plus the ``fused_moe``
     capability flag: ``moe_ffn_blaze`` routes whole SwiGLU layers through
     the fused dispatch→GEMM→combine kernel pair (no ``(L·k, ·)``
@@ -34,11 +32,10 @@ Selection precedence (``resolve``):
   3. a config field (``ModelConfig.gmm_backend`` / ``TrainConfig.gmm_backend``,
      passed via ``resolve(..., config=...)``),
   4. the ``REPRO_GMM_BACKEND`` environment variable,
-  5. auto (first available of ``ragged``, ``segment``).
+  5. auto: ``ragged``.
 
-``pallas`` / ``pallas_fused`` are never auto-selected: in interpret mode they
-are orders of magnitude slower than the XLA paths and exist as explicitly
-requested kernel-validation targets.
+``pallas`` / ``pallas_fused`` are never auto-selected: no chip measurement
+has yet shown them faster than ``ragged``; they are requested explicitly.
 
     REPRO_GMM_BACKEND=segment python -m pytest -q          # force portable
     gmm(lhs, rhs, sizes, backend="ragged")                  # force fast path
@@ -51,9 +48,6 @@ is recorded in a :class:`ResolvedBackend` carrying the name plus jax-version
 provenance.  Long-lived objects (``ServeEngine``, train steps) resolve once
 at construction and hold the ``ResolvedBackend`` — mutating the environment
 afterwards cannot retarget them.
-
-The JAX-version support matrix lives in README.md; ``available_backends()``
-reports what works on the running install.
 """
 
 from __future__ import annotations
@@ -68,8 +62,8 @@ import jax.numpy as jnp
 
 ENV_VAR = "REPRO_GMM_BACKEND"
 
-# Auto-selection order: fast XLA path first, portable fallback second.
-_AUTO_PRIORITY = ("ragged", "segment")
+#: the auto choice: the XLA grouped GEMM.
+_AUTO = "ragged"
 
 #: the innermost active ``use_backend`` scope (None when outside any scope).
 _ACTIVE: ContextVar[str | None] = ContextVar("repro_gmm_backend", default=None)
@@ -92,16 +86,16 @@ class RaggedBackend:
     name = "ragged"
 
     @staticmethod
-    def available() -> bool:
-        return (hasattr(jax.lax, "ragged_dot")
-                and hasattr(jax.lax, "ragged_dot_general")
-                and hasattr(jax.lax, "RaggedDotDimensionNumbers"))
-
-    @staticmethod
     def gmm(lhs, rhs, group_sizes):
-        out = jax.lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+        gs = group_sizes.astype(jnp.int32)
+        out = jax.lax.ragged_dot(lhs, rhs, gs,
                                  preferred_element_type=jnp.float32)
-        return out.astype(lhs.dtype)
+        # Rows past the group total belong to no group.  XLA's TPU kernel
+        # leaves them unwritten (whatever the buffer held, NaN included);
+        # the contract — and the dead zone of a sliced dispatch — needs
+        # zeros.
+        rows = jnp.arange(lhs.shape[0], dtype=jnp.int32)[:, None]
+        return jnp.where(rows < gs.sum(), out, 0).astype(lhs.dtype)
 
     @staticmethod
     def gmm_dw(lhs, dout, group_sizes):
@@ -125,10 +119,6 @@ class SegmentBackend:
     """
 
     name = "segment"
-
-    @staticmethod
-    def available() -> bool:
-        return True
 
     @staticmethod
     def gmm(lhs, rhs, group_sizes):
@@ -165,15 +155,12 @@ class SegmentBackend:
 
 def _pallas_gmm_impl(lhs, rhs, group_sizes):
     from repro.kernels.gather_gmm import gather_gmm
-    S = lhs.shape[0]
     # Backend contract: rows past the group-size total belong to no group and
-    # are exact zeros.  The kernel now guarantees this itself: rows inside a
-    # visited tile are zeroed by the in-tile gather mask, and tiles no work
-    # item visits are zero-initialized in-kernel by make_work_items' filler
-    # items (``bh`` is likewise clamped to a divisor of h in-kernel).
-    return gather_gmm(lhs, jnp.arange(S, dtype=jnp.int32),
-                      _offsets_of(group_sizes), rhs,
-                      epilogue=False, interpret=True)
+    # are exact zeros.  The kernel guarantees this itself: rows inside a
+    # visited tile are zeroed by the in-tile row mask, and tiles past the
+    # total are zero-initialized by make_work_items' filler items.
+    return gather_gmm(lhs, None, _offsets_of(group_sizes), rhs,
+                      epilogue=False)
 
 
 def _pallas_dw_impl(lhs, dout, group_sizes):
@@ -181,7 +168,7 @@ def _pallas_dw_impl(lhs, dout, group_sizes):
     # Empty experts' (1, d, h) blocks are zero-initialized in-kernel (each
     # empty expert gets a dedicated efirst filler item) — no caller-side
     # masking needed.
-    return gmm_dw_pallas(lhs, dout, _offsets_of(group_sizes), interpret=True)
+    return gmm_dw_pallas(lhs, dout, _offsets_of(group_sizes))
 
 
 # ``pallas_call`` has no JVP rule, so the kernels are wrapped in custom VJPs
@@ -230,19 +217,10 @@ _pallas_dw.defvjp(_pallas_dw_fwd, _pallas_dw_bwd)
 
 
 class PallasBackend:
-    """The ``kernels/gather_gmm.py`` work-item kernels with an identity
-    gather (rows already in expert order).  ``interpret=True`` on CPU; on a
-    real TPU the same grid/work-item structure lowers natively."""
+    """The ``kernels/gather_gmm.py`` work-item kernels on rows already in
+    expert order — compiled on a TPU, interpreted on the CPU."""
 
     name = "pallas"
-
-    @staticmethod
-    def available() -> bool:
-        try:
-            import repro.kernels.gather_gmm  # noqa: F401
-        except Exception:  # pragma: no cover - import guard
-            return False
-        return True
 
     @staticmethod
     def gmm(lhs, rhs, group_sizes):
@@ -263,8 +241,8 @@ class PallasFusedBackend(PallasBackend):
     GEMM and the gated combine run inside the same grid pass and the
     backward replays the gather in-kernel — no ``(L·k, h)`` / ``(L·k, d)``
     intermediate exists in HBM in either direction.  Tile sizes come from
-    ``repro.roofline.select_moe_tiles``.  Never auto-selected (interpret
-    mode on CPU); request it explicitly like ``pallas``.
+    ``repro.roofline.select_moe_tiles``.  Never auto-selected; request it
+    explicitly like ``pallas``.
     """
 
     name = "pallas_fused"
@@ -285,20 +263,15 @@ _REGISTRY: dict[str, object] = {
 
 
 def backend_names() -> list[str]:
-    """All registered backend names (available or not)."""
+    """All registered backend names."""
     return list(_REGISTRY)
-
-
-def available_backends() -> list[str]:
-    """Backends that work on the running JAX install."""
-    return [n for n, b in _REGISTRY.items() if b.available()]
 
 
 @dataclass(frozen=True)
 class ResolvedBackend:
     """A concrete, validated backend choice with provenance.
 
-    ``name`` is always a registered, available backend; ``source`` records
+    ``name`` is always a registered backend; ``source`` records
     which precedence slot won (``arg`` | ``context`` | ``config`` | ``env`` |
     ``auto``); ``jax_version`` is the install the resolution was made on —
     together they make a BENCH record / step metric self-describing in mixed
@@ -322,10 +295,6 @@ def _validate(name: str) -> str:
     if name not in _REGISTRY:
         raise ValueError(
             f"unknown gmm backend {name!r}; known: {backend_names()}")
-    if not _REGISTRY[name].available():
-        raise RuntimeError(
-            f"gmm backend {name!r} is not available on jax "
-            f"{jax.__version__}; available: {available_backends()}")
     return name
 
 
@@ -336,8 +305,8 @@ def use_backend(name: str | None):
     Sits between the call-site argument and config fields in the precedence
     chain, so ``with use_backend("segment"):`` retargets a whole train step /
     engine batch without touching configs or the process environment.  The
-    name is validated eagerly (entering the scope raises on an unknown or
-    unavailable backend); ``None``/"auto" makes the scope fully transparent —
+    name is validated eagerly (entering the scope raises on an unknown
+    backend); ``None``/"auto" makes the scope fully transparent —
     it neither selects nor masks an enclosing scope, so helpers can forward
     an optional pin via ``with use_backend(maybe_none):`` safely.  Scopes
     nest — the innermost non-transparent one wins."""
@@ -363,7 +332,7 @@ def resolve(backend: str | ResolvedBackend | None = None, *,
 
     Precedence: ``backend`` call-site argument > active :func:`use_backend`
     context > ``config`` (a ``gmm_backend`` config field) > the
-    ``REPRO_GMM_BACKEND`` environment variable > auto priority.  A
+    ``REPRO_GMM_BACKEND`` environment variable > auto (``ragged``).  A
     ``ResolvedBackend`` passed as ``backend`` is returned unchanged (already
     resolved upstream — threading it is free of re-resolution surprises)."""
     if isinstance(backend, ResolvedBackend):
@@ -375,17 +344,12 @@ def resolve(backend: str | ResolvedBackend | None = None, *,
     for source, cand in chain:
         if not _unset(cand):
             return ResolvedBackend(_validate(cand), source, jax.__version__)
-    for cand in _AUTO_PRIORITY:
-        if _REGISTRY[cand].available():
-            return ResolvedBackend(cand, "auto", jax.__version__)
-    raise RuntimeError(
-        "no grouped-GEMM backend available on this JAX install "
-        f"(jax {jax.__version__})")
+    return ResolvedBackend(_AUTO, "auto", jax.__version__)
 
 
 def resolve_backend_name(name: str | ResolvedBackend | None = None, *,
                          config: str | None = None) -> str:
-    """Resolve to a concrete, available backend *name* (:func:`resolve`
+    """Resolve to a concrete backend *name* (:func:`resolve`
     without the provenance — kept for call sites that only need the str)."""
     return resolve(name, config=config).name
 

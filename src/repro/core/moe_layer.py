@@ -30,8 +30,8 @@ The residual set is a per-plan decision (``repro.core.checkpoint``
     ``moe:recompute=ffn_a,ffn_b`` plan can ask for.
 
 The grouped GEMMs go through the pluggable backend registry in
-``repro.core.gmm_backend`` (``ragged`` = ``jax.lax.ragged_dot[_general]``
-where available, ``segment`` = portable pure-jnp fallback, ``pallas`` = the
+``repro.core.gmm_backend`` (``ragged`` = ``jax.lax.ragged_dot[_general]``,
+the auto choice, ``segment`` = pure-jnp oracle, ``pallas`` = the
 ``repro.kernels`` work-item kernels); select per call via ``backend=`` or
 globally via ``REPRO_GMM_BACKEND``.  The ``pallas_fused`` backend short-
 circuits the whole SwiGLU layer into the fused dispatch→GEMM→combine kernel

@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the MoEBlaze hot spots (``ops.py`` holds the
+differentiable entry points, ``ref.py`` the pure-jnp oracles)."""
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run through the interpreter.
+
+    The one place that decides it: True only when JAX's default backend is
+    the CPU (tests and the CPU rehearsal).  On a TPU every kernel is compiled
+    by Mosaic; nothing falls back to the interpreter or to another backend.
+    """
+    return jax.default_backend() == "cpu"

@@ -20,55 +20,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+from repro.kernels.common import (compiler_params, dma_rows, row_words,
+                                  rows_from_words)
 
-def _combine_kernel(tim_ref, p_ref, g_ref, y_ref, *, bl: int, k: int,
-                    n_rows: int):
+
+def _combine_kernel(tim_ref, p_ref, g_ref, y_ref, buf_ref, sem, *, bl: int,
+                    k: int, dtype):
     t = pl.program_id(0)
-
-    def row(r, _):
-        tok = t * bl + r
-        valid = tok < n_rows
-        acc = jnp.zeros((1, p_ref.shape[1]), jnp.float32)
-        for i in range(k):                       # k is small and static
-            slot = jnp.where(valid, tim_ref[tok * k + i], 0)
-            part = pl.load(p_ref, (pl.ds(slot, 1), slice(None)))
-            acc = acc + g_ref[r, i].astype(jnp.float32) * \
-                part.astype(jnp.float32)
-        y_ref[pl.ds(r, 1), :] = acc.astype(y_ref.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, bl, row, 0, unroll=False)
+    # buf row i*bl + r <- the partial of token t*bl + r's i-th slot.
+    for i in range(k):                           # k is small and static
+        dma_rows(p_ref, buf_ref, sem, 0, bl,
+                 lambda r, i=i: tim_ref[(t * bl + r) * k + i],
+                 lambda r, i=i: i * bl + r)
+    acc = jnp.zeros(y_ref.shape, jnp.float32)
+    for i in range(k):
+        part = rows_from_words(buf_ref[pl.ds(i * bl, bl)], dtype)
+        acc = acc + g_ref[:, i:i + 1].astype(jnp.float32) * \
+            part.astype(jnp.float32)
+    y_ref[...] = acc.astype(y_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "bd", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl",))
 def combine(p_out: jax.Array, token_index_map: jax.Array, gates: jax.Array,
-            *, bl: int = 128, bd: int = 512, interpret: bool = True):
+            *, bl: int = 128):
     """(S, d) partials + (L, k) map + (L, k) gates -> (L, d) output.
 
-    ``bd`` is clamped to the largest divisor of ``d`` (same contract as the
-    ``bh`` clamp in ``gather_gmm``: any width traces, non-divisible ones
-    just run a narrower tile)."""
-    from repro.kernels.gather_gmm import largest_divisor_tile
+    Each grid step gathers its ``bl`` tokens' ``k`` partial rows by row DMA
+    (``p_out`` stays in HBM), so VMEM is bounded by ``k * bl`` rows."""
     S, d = p_out.shape
     L, k = token_index_map.shape
     bl = min(bl, L)
-    bd = largest_divisor_tile(d, bd)
     L_pad = ((L + bl - 1) // bl) * bl
-    tim = token_index_map.reshape(-1).astype(jnp.int32)
+    tim = jnp.pad(token_index_map.astype(jnp.int32),
+                  ((0, L_pad - L), (0, 0))).reshape(-1)
     g = jnp.pad(gates, ((0, L_pad - L), (0, 0)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(L_pad // bl, d // bd),
-        in_specs=[
-            pl.BlockSpec((S, bd), lambda t, dd, tim_r: (0, dd)),
-            pl.BlockSpec((bl, k), lambda t, dd, tim_r: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((bl, bd), lambda t, dd, tim_r: (t, dd)),
-    )
+    pw = row_words(p_out)
+    it = p_out.dtype.itemsize
     y = pl.pallas_call(
-        functools.partial(_combine_kernel, bl=bl, k=k, n_rows=L),
-        grid_spec=grid_spec,
+        functools.partial(_combine_kernel, bl=bl, k=k, dtype=p_out.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(L_pad // bl,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((bl, k), lambda t, tim_r: (t, 0)),
+            ],
+            out_specs=pl.BlockSpec((bl, d), lambda t, tim_r: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((k * bl, 1, pw.shape[-1]), pw.dtype),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
         out_shape=jax.ShapeDtypeStruct((L_pad, d), p_out.dtype),
-        interpret=interpret,
-    )(tim, p_out, g)
+        compiler_params=compiler_params(
+            "combine", k * bl * d * it + 2 * bl * d * it + 2 * bl * d * 4
+            + 2 * bl * 128 * 4),
+        interpret=kernels.interpret_mode(),
+    )(tim, pw, g)
     return y[:L]

@@ -9,7 +9,7 @@ logit softcap (gemma2).  Backward uses XLA autodiff over the pure-jnp
 reference (attention backward is not a paper contribution; the fwd kernel is
 the serving/prefill hot spot).
 
-Validated in interpret mode against ``ref.py``/`models.attention` on CPU.
+Checked in interpret mode on the CPU against ``models.attention``.
 """
 
 from __future__ import annotations
@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import kernels
 
 NEG_INF = -1e30
 
@@ -68,11 +70,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "cap",
-                                             "bq", "bk", "interpret"))
+                                             "bq", "bk"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
-                           cap: float = 0.0, bq: int = 128, bk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           cap: float = 0.0, bq: int = 128, bk: int = 128) -> jax.Array:
     """q: (B, S, H, Dh); k, v: (B, S, Hkv, Dh) with H % Hkv == 0.
     Returns (B, S, H, Dh)."""
     B, S, H, Dh = q.shape
@@ -101,7 +102,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(qf, kf, vf)
     return out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
 

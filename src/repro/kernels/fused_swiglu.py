@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 
 def _silu(a):
     return a * jax.nn.sigmoid(a)
@@ -65,10 +67,9 @@ def _fwd_kernel(x_ref, w1_ref, w2_ref, y_ref, a_ref, b_ref,
         y_ref[...] = (_silu(a) * b).astype(y_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "bh", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl", "bh", "bk"))
 def fused_swiglu_fwd(x: jax.Array, w1: jax.Array, w2: jax.Array,
-                     *, bl: int = 128, bh: int = 128, bk: int = 128,
-                     interpret: bool = True):
+                     *, bl: int = 128, bh: int = 128, bk: int = 128):
     """Returns ``(y_swi, a, b)`` with a single pass over ``x``."""
     L, d = x.shape
     _, h = w1.shape
@@ -87,7 +88,7 @@ def fused_swiglu_fwd(x: jax.Array, w1: jax.Array, w2: jax.Array,
         out_specs=[pl.BlockSpec((bl, bh), lambda l, hh, kk: (l, hh))] * 3,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((bl, bh), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x, w1, w2)
     return y, a, b
 
@@ -120,11 +121,10 @@ def _bwd_x_kernel(dy_ref, a_ref, b_ref, w1_ref, w2_ref, dx_ref,
         dx_ref[...] = acc[...].astype(dx_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "bd", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl", "bd", "bk"))
 def fused_swiglu_bwd_x(dy: jax.Array, a: jax.Array, b: jax.Array,
                        w1: jax.Array, w2: jax.Array,
-                       *, bl: int = 128, bd: int = 128, bk: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       *, bl: int = 128, bd: int = 128, bk: int = 128) -> jax.Array:
     L, h = dy.shape
     d = w1.shape[0]
     bl, bd, bk = min(bl, L), min(bd, d), min(bk, h)
@@ -143,7 +143,7 @@ def fused_swiglu_bwd_x(dy: jax.Array, a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((bl, bd), lambda l, dd, kk: (l, dd)),
         out_shape=jax.ShapeDtypeStruct((L, d), dy.dtype),
         scratch_shapes=[pltpu.VMEM((bl, bd), jnp.float32)],
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(dy, a, b, w1, w2)
 
 
@@ -176,11 +176,10 @@ def _bwd_w_kernel(x_ref, dy_ref, a_ref, b_ref, dw1_ref, dw2_ref,
         dw2_ref[...] = acc2[...].astype(dw2_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bd", "bh", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bd", "bh", "bk"))
 def fused_swiglu_bwd_w(x: jax.Array, dy: jax.Array, a: jax.Array,
                        b: jax.Array,
-                       *, bd: int = 128, bh: int = 128, bk: int = 128,
-                       interpret: bool = True):
+                       *, bd: int = 128, bh: int = 128, bk: int = 128):
     L, d = x.shape
     h = dy.shape[1]
     bd, bh, bk = min(bd, d), min(bh, h), min(bk, L)
@@ -198,5 +197,5 @@ def fused_swiglu_bwd_w(x: jax.Array, dy: jax.Array, a: jax.Array,
         out_specs=[pl.BlockSpec((bd, bh), lambda dd, hh, kk: (dd, hh))] * 2,
         out_shape=[jax.ShapeDtypeStruct((d, h), x.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((bd, bh), jnp.float32)] * 2,
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x, dy, a, b)

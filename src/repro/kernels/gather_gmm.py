@@ -6,58 +6,53 @@ This is the kernel rendering of the paper's central claim: the expert MLPs
 consume **non-materialized** routed tokens.  The `(L·k, d)` routed buffer
 never exists in HBM; instead the kernel is driven by the scalar-prefetched
 ``expert_token_indices`` and DMA-gathers the needed rows of the *unpermuted*
-``x`` per tile, streams them through the expert's projections (optionally both
-SwiGLU branches at once, sharing the single read of the gathered rows), and
-applies the SiLU·gate epilogue in VMEM.
+``x`` (kept in HBM, ``memory_space=pl.ANY``) per work item, streams them
+through the expert's projections (optionally both SwiGLU branches at once,
+sharing the single read of the gathered rows), and applies the SiLU·gate
+epilogue in VMEM.
 
 :func:`fused_moe_fwd` / :func:`fused_moe_bwd` take the fusion end to end
 (SonicMoE-style IO-aware epilogue fusion): the second grouped GEMM
 (``y_swi @ w3[e]``) runs in the same grid pass, and each slot's gated partial
-is scatter-accumulated straight into the `(L, d)` output through the same
-index metadata — the gather-of-partials combine of ``kernels/combine.py``
-becomes the kernel's epilogue, so neither the `(L·k, h)` SwiGLU product nor
-the `(L·k, d)` partials ever exist in HBM.  The backward replays the gather
-in-kernel and produces dx / dgates / dw1 / dw2 / dw3 from one grid sweep,
-again with no `(L·k, ·)` residual.  The fused kernels express both the
-gather and the scatter-accumulate as one-hot matmuls against a per-item
-``(bl, L)`` dispatch matrix built in VMEM (``sel @ x`` / ``selᵀ @ v`` — MXU
-work instead of per-row dynamic slices; exact, since entries are 0/1 with at
-most one hit per row).
+is scatter-accumulated straight into the `(L, d)` output in HBM through the
+same index metadata (row DMAs: read, add, write back) — the gather-of-partials
+combine of ``kernels/combine.py`` becomes the kernel's epilogue, so neither
+the `(L·k, h)` SwiGLU product nor the `(L·k, d)` partials ever exist in HBM.
+The backward replays the gather in-kernel and produces dx / dgates / dw1 /
+dw2 / dw3 from one grid sweep, again with no `(L·k, ·)` residual.
+
+Every kernel's VMEM is bounded by its tile sizes, never by ``L`` or ``S``:
+token rows move one DMA per row (``kernels/common.py``), and weight and
+weight-gradient blocks are tiled over the hidden (and, for ``gmm_dw``, the
+model) width.
 
 Group-crossing tiles are handled MegaBlocks-style: the wrapper precomputes a
 static work-item list (one item per (row-tile × overlapping expert); at most
 ``n_tiles + E`` items) whose metadata — tile id, expert id, row range inside
 the tile, first-visit flags — is scalar-prefetched so that the weight
-BlockSpec's ``index_map`` can select ``w[expert]`` per work item.  Output
-tiles visited by several experts are accumulated in VMEM across consecutive
-grid steps (TPU grids are sequential per core).
+BlockSpec's ``index_map`` can select ``w[expert]`` per work item.
 
-Work-item contracts (hardened; see :func:`make_work_items`):
+Work-item contracts (see :func:`make_work_items`):
 
-  * every output row tile is zero-initialized in-kernel — tiles no expert
-    touches get a dedicated filler item with ``first=1``, so trailing dead
-    rows are exact zeros, not uninitialized memory;
-  * every expert's weight-gradient block is zero-initialized in-kernel —
-    empty experts get a dedicated filler item with ``efirst=1``, so callers
-    no longer have to mask ``gmm_dw_pallas`` outputs;
+  * items are ordered so that both the tile id and the expert id are
+    non-decreasing: every row-tiled and every per-expert output block is
+    visited in ONE run of consecutive grid steps.  A compiled TPU grid writes
+    an output block back when its index changes and never reads it back in,
+    so a later revisit would overwrite a finished block with stale VMEM;
+  * every output row tile is zero-initialized in-kernel (``first`` marks the
+    first item of each tile's run) — tiles no expert touches get an
+    empty-range filler item, so trailing dead rows are exact zeros;
+  * every expert's weight-gradient block is zero-initialized in-kernel
+    (``efirst``) — empty experts get an empty-range item in their place in
+    the expert order, so callers never mask ``gmm_dw_pallas`` outputs;
   * the all-empty case (``n_valid == 0``, e.g. an ``ep_a2a`` shard whose
     tokens were all dropped) degenerates to pure no-op items that still
     zero-initialize every output block.
 
-Tile sizes: ``bl``/``bh`` are *requests*; ``bh`` is clamped to the largest
-divisor of ``h`` (non-multiple-of-128 FFN widths work, they just run a
-narrower tile) and ``bl`` to the padded row count.  Callers that want
-hardware-informed sizes ask ``repro.roofline.select_moe_tiles`` (the
-arithmetic-intensity model) instead of hard-coding 128.
-
-On this CPU container the kernels run in ``interpret=True`` mode; ``x`` is
-held as a single VMEM block for kernel-scale shapes.  On a real TPU the same
-grid/work-item structure applies with ``x`` in ``ANY`` (HBM) memory space and
-per-row ``make_async_copy`` gathers — the row (``d`` contiguous elements) is
-the natural DMA unit, see DESIGN.md §2.  (The filler items appended by the
-hardened :func:`make_work_items` revisit some output blocks non-adjacently;
-on a real TPU grid they must be folded into the per-block visit order —
-tracked under the ROADMAP real-hardware item.)
+Tile sizes: ``bl``/``bh`` are *requests*; ``bh`` is clamped by
+:func:`repro.kernels.common.lane_tile` (a multiple of 128 dividing ``h``, or
+all of ``h``) and ``bl`` to the padded row count.  Callers that want
+hardware-informed sizes ask ``repro.roofline.select_moe_tiles``.
 """
 
 from __future__ import annotations
@@ -69,6 +64,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+from repro.kernels.common import (compiler_params, dma_rows, lane_tile,
+                                  row_words, rows_from_words)
+
 
 def _silu(a):
     return a * jax.nn.sigmoid(a)
@@ -79,17 +78,10 @@ def _dsilu(a):
     return s * (1.0 + a * (1.0 - s))
 
 
-def largest_divisor_tile(n: int, b: int) -> int:
-    """Largest divisor of ``n`` that is ``<= b`` (static Python ints).
-
-    The tile-size clamp for block dimensions that must divide the array
-    dimension exactly: ``largest_divisor_tile(192, 128) == 96``.  Always
-    >= 1, so any positive ``n`` has a valid tiling.
-    """
-    b = max(1, min(int(b), int(n)))
-    while n % b:
-        b -= 1
-    return b
+def _row_tiles(S: int, bl: int) -> tuple[int, int]:
+    """(bl, S_pad): the row-tile request clamped to the row count."""
+    bl = min(bl, max(S, 8))
+    return bl, -(-S // bl) * bl
 
 
 def make_work_items(offsets: jax.Array, n_tiles: int, bl: int,
@@ -98,225 +90,182 @@ def make_work_items(offsets: jax.Array, n_tiles: int, bl: int,
 
     Returns int32 arrays of length ``W = n_tiles + num_experts``:
       (tile, expert, lo, hi, first, efirst) — ``[lo, hi)`` is the row range
-    of ``expert`` inside ``tile``; ``first`` marks the first item visiting
-    each *tile's* output block and ``efirst`` the first item visiting each
-    *expert's* block (whichever output block a kernel accumulates into must
-    be initialized on its first visit).
+    of ``expert`` inside ``tile``; ``first`` marks the first item of each
+    tile's run and ``efirst`` the first item of each expert's run.
 
-    The trailing (invalid) items are structured fillers, not garbage:
+    The order is expert-major: expert ``e``'s items cover the tiles its row
+    segment ``[offsets[e], offsets[e+1])`` spans, in tile order; an empty
+    expert gets one empty-range item on the tile where its (empty) segment
+    sits.  Segments are contiguous and sorted, so the tile id never
+    decreases either, and every tile and every expert is visited in one run
+    of consecutive items.  Tiles past the last routed row follow, one
+    empty-range item each (on expert ``E-1``, the last run); remaining items
+    repeat the last tile with an empty range.
 
-      1. one item per **unvisited tile** (no expert has rows there — dead
-         rows past the group totals) carrying ``first=1`` and an empty row
-         range, so row-tiled outputs are zero-initialized in-kernel;
-      2. one item per **empty expert** carrying ``efirst=1`` and an empty
-         range, so per-expert outputs (the dw kernels) are zero-initialized
-         in-kernel;
-      3. any remaining items are benign no-ops on already-initialized blocks
-         (last tile / last valid expert, empty range, flags clear).
-
-    Counting argument for why the fillers always fit: contiguous expert row
-    ranges over ``T`` tiles give ``n_valid <= T_visited + E_nonempty - 1``
-    (0 when nothing is routed), so ``W - n_valid >= #unvisited_tiles +
-    #empty_experts`` always holds — including the fully degenerate
-    ``n_valid == 0`` case, where the items are exactly one ``first`` filler
-    per tile followed by one ``efirst`` filler per expert (all-empty input
-    produces well-defined, all-zero outputs instead of self-referential
-    metadata).
+    Why ``W`` items always suffice: consecutive non-empty experts share at
+    most one tile, so the expert-major items number at most
+    ``last_tile + 1 + E - 1``, and the trailing tiles ``n_tiles - 1 -
+    last_tile`` more — ``n_tiles + E - 1 < W`` in all, including the
+    all-empty case (``E`` items on tile 0, then tiles ``1..n_tiles-1``).
     """
     E = num_experts
     W = n_tiles + E
-    t = jnp.arange(n_tiles, dtype=jnp.int32)[:, None]           # (T, 1)
-    lo = jnp.clip(offsets[None, :E] - t * bl, 0, bl)             # (T, E)
-    hi = jnp.clip(offsets[None, 1:] - t * bl, 0, bl)             # (T, E)
-    valid = (hi > lo)
-    flat_valid = valid.reshape(-1)
-    rank = jnp.cumsum(flat_valid) - flat_valid                   # dest slot
-    first = valid & (jnp.cumsum(valid, axis=1) == 1)
-    efirst = valid & (jnp.cumsum(valid, axis=0) == 1)
+    off = offsets.astype(jnp.int32)
+    start, end = off[:E], off[1:E + 1]
+    t0 = jnp.minimum(start // bl, n_tiles - 1)
+    t1 = jnp.where(end > start, (end - 1) // bl, t0)
+    count = t1 - t0 + 1                                  # items per expert
+    ends = jnp.cumsum(count)
+    n_main = ends[-1]
+    w = jnp.arange(W, dtype=jnp.int32)
+    e = jnp.minimum((ends[None, :] <= w[:, None]).sum(axis=1), E - 1)
+    e = e.astype(jnp.int32)
+    in_main = w < n_main
+    tile = jnp.where(in_main, t0[e] + w - (ends[e] - count[e]),
+                     jnp.minimum(t1[E - 1] + 1 + w - n_main, n_tiles - 1))
+    expert = jnp.where(in_main, e, E - 1)
+    lo = jnp.where(in_main, jnp.clip(start[e] - tile * bl, 0, bl), 0)
+    hi = jnp.where(in_main, jnp.clip(end[e] - tile * bl, 0, bl), 0)
 
-    def scatter(vals, fill):
-        out = jnp.full((W,), fill, jnp.int32)
-        return out.at[jnp.where(flat_valid, rank, W)].set(
-            jnp.where(flat_valid, vals.reshape(-1).astype(jnp.int32), fill),
-            mode="drop")
+    def run_starts(v):
+        return (v != jnp.concatenate([jnp.full((1,), -1, v.dtype), v[:-1]])
+                ).astype(jnp.int32)
 
-    n_valid = flat_valid.sum()
-    ex = jnp.broadcast_to(jnp.arange(E, dtype=jnp.int32)[None, :],
-                          (n_tiles, E))
-    tiles = jnp.broadcast_to(t, (n_tiles, E))
-    wi_tile = scatter(tiles, n_tiles - 1)
-    wi_expert = scatter(ex, 0)
-    wi_lo = scatter(lo, 0)
-    wi_hi = scatter(hi, 0)
-    wi_first = scatter(first, 0)
-    wi_efirst = scatter(efirst, 0)
-    # Benign filler base: empty range on the last tile, pointing at the last
-    # valid item's expert (expert 0 when nothing is valid) so block revisits
-    # only ever touch initialized blocks.
-    fill_mask = jnp.arange(W) >= n_valid
-    last_expert = wi_expert[jnp.maximum(n_valid - 1, 0)]
-    wi_tile = jnp.where(fill_mask, n_tiles - 1, wi_tile)
-    wi_expert = jnp.where(fill_mask, last_expert, wi_expert)
-    wi_lo = jnp.where(fill_mask, 0, wi_lo)
-    wi_hi = jnp.where(fill_mask, 0, wi_hi)
-    wi_first = jnp.where(fill_mask, 0, wi_first)
-    wi_efirst = jnp.where(fill_mask, 0, wi_efirst)
-    # Filler class 1: unvisited tiles get a `first=1` item each, directly
-    # after the valid items, so their output blocks are zeroed in-kernel.
-    ut = ~valid.any(axis=1)                                      # (T,)
-    ut_rank = n_valid + jnp.cumsum(ut) - ut
-    ut_idx = jnp.where(ut, ut_rank, W)
-    tile_ids = jnp.arange(n_tiles, dtype=jnp.int32)
-    wi_tile = wi_tile.at[ut_idx].set(tile_ids, mode="drop")
-    wi_first = wi_first.at[ut_idx].set(1, mode="drop")
-    # Filler class 2: empty experts get an `efirst=1` item each (after the
-    # tile fillers, so the last tile's block they sit on is initialized).
-    ue = ~valid.any(axis=0)                                      # (E,)
-    ue_rank = n_valid + ut.sum() + jnp.cumsum(ue) - ue
-    ue_idx = jnp.where(ue, ue_rank, W)
-    expert_ids = jnp.arange(E, dtype=jnp.int32)
-    wi_expert = wi_expert.at[ue_idx].set(expert_ids, mode="drop")
-    wi_efirst = wi_efirst.at[ue_idx].set(1, mode="drop")
-    return wi_tile, wi_expert, wi_lo, wi_hi, wi_first, wi_efirst
+    return (tile.astype(jnp.int32), expert, lo.astype(jnp.int32),
+            hi.astype(jnp.int32), run_starts(tile), run_starts(expert))
 
 
-def _kernel(idx_ref, tile_ref, expert_ref, lo_ref, hi_ref, first_ref,
-            x_ref, w1_ref, w2_ref, y_ref, a_ref, b_ref, xt_ref,
-            *, bl: int, dual: bool, epilogue: bool):
-    wi = pl.program_id(0)
-    tile = tile_ref[wi]
+def _accumulate(ref, val, init):
+    """``ref = val`` on the first visit of the block, ``ref += val`` after."""
+    @pl.when(init)
+    def _init():
+        ref[...] = val.astype(ref.dtype)
+
+    @pl.when(jnp.logical_not(init))
+    def _acc():
+        ref[...] += val.astype(ref.dtype)
+
+
+def _row_mask(lo, hi, bl: int):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bl, 1), 0)
+    return (rows >= lo) & (rows < hi)
+
+
+# ---------------------------------------------------------------------------
+# Gather-GMM (the ``pallas`` grouped-GEMM backend and the unfused layer)
+# ---------------------------------------------------------------------------
+
+
+def _gmm_kernel(*refs, bl: int, dual: bool, epilogue: bool, n_out: int,
+                gather: bool, dtype):
+    tile_ref, _, lo_ref, hi_ref, first_ref = refs[:5]
+    i = 5
+    if gather:
+        idx_ref = refs[i]
+        i += 1
+    x_ref, w1_ref = refs[i:i + 2]
+    i += 2
+    w2_ref = None
+    if dual:
+        w2_ref = refs[i]
+        i += 1
+    outs = refs[i:i + n_out]
+    wi = pl.program_id(1)
     lo, hi = lo_ref[wi], hi_ref[wi]
-    first = first_ref[wi] == 1
-
-    # --- on-the-fly gather of this work item's rows into VMEM -------------
-    def gather_row(r, _):
-        active = (r >= lo) & (r < hi)
-        tok = jnp.where(active, idx_ref[tile * bl + r], 0)
-        row = pl.load(x_ref, (pl.ds(tok, 1), slice(None)))
-        xt_ref[pl.ds(r, 1), :] = jnp.where(active, row, 0)
-        return 0
-
-    jax.lax.fori_loop(0, bl, gather_row, 0, unroll=False)
-
-    xt = xt_ref[...]
+    if gather:
+        # On-the-fly gather: exactly this item's rows, one DMA each.
+        xt_ref, sem = refs[i + n_out:]
+        base = tile_ref[wi] * bl
+        dma_rows(x_ref, xt_ref, sem, lo, hi, lambda r: idx_ref[base + r],
+                 lambda r: r)
+        xt = rows_from_words(xt_ref[...], dtype)
+    else:
+        xt = x_ref[...]
+    # Rows of the tile outside [lo, hi) belong to other experts (or hold a
+    # previous item's gather): zero them so the full-tile dot is exact.
+    xt = jnp.where(_row_mask(lo, hi, bl), xt, jnp.zeros((), xt.dtype))
     a = jnp.dot(xt, w1_ref[0], preferred_element_type=jnp.float32)
+    b = None
+    y = a
     if dual:
         b = jnp.dot(xt, w2_ref[0], preferred_element_type=jnp.float32)
-        y = _silu(a) * b if epilogue else a
-    else:
-        b = None
-        y = a
-
-    def acc(ref, val):
-        @pl.when(first)
-        def _init():
-            ref[...] = val.astype(ref.dtype)
-
-        @pl.when(jnp.logical_not(first))
-        def _acc():
-            ref[...] += val.astype(ref.dtype)
-
-    acc(y_ref, y)
-    if a_ref is not None:
-        acc(a_ref, a)
-    if dual and b_ref is not None:
-        acc(b_ref, b)
+        if epilogue:
+            y = _silu(a) * b
+    first = first_ref[wi] == 1
+    for ref, val in zip(outs, (y, a, b)):
+        _accumulate(ref, val, first)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "bl", "bh", "epilogue", "save_ab", "interpret"))
-def gather_gmm(x: jax.Array, idx: jax.Array, offsets: jax.Array,
+@functools.partial(jax.jit, static_argnames=("bl", "bh", "epilogue",
+                                             "save_ab"))
+def gather_gmm(x: jax.Array, idx: jax.Array | None, offsets: jax.Array,
                w1: jax.Array, w2: jax.Array | None = None,
-               *, bl: int = 128, bh: int = 128, epilogue: bool = True,
-               save_ab: bool = False, interpret: bool = True):
+               *, bl: int = 128, bh: int = 512, epilogue: bool = True,
+               save_ab: bool = False):
     """Grouped matmul over gathered rows.
 
     Args:
       x: (L, d) unpermuted activations.
-      idx: (S,) row ids grouped by expert (``expert_token_indices``).
+      idx: (S,) row ids grouped by expert (``expert_token_indices``), or
+        None when ``x`` is already in expert order (``L == S``; the rows
+        then stream as plain blocks, no gather).
       offsets: (E+1,) exclusive prefix sums (``expert_token_offsets``).
       w1: (E, d, h); w2: optional (E, d, h) SwiGLU gate branch.
       epilogue: apply ``silu(a)·b`` (requires w2).
       save_ab: also return the checkpointed GEMM outputs a (and b).
-      bl/bh: row/hidden tile-size *requests* — ``bh`` is clamped to the
-        largest divisor of ``h`` (any FFN width traces; a non-multiple of
-        128 just runs a narrower tile) and ``bl`` to the padded row count.
+      bl/bh: row/hidden tile-size *requests* (see the module docstring).
 
     Returns ``y`` of shape (S, h) — or ``(y, a[, b])`` when ``save_ab``.
-    Output rows past ``offsets[-1]`` belong to no group and are exact zeros
-    (unvisited tiles are zero-initialized in-kernel by the filler items).
+    Output rows past ``offsets[-1]`` belong to no group and are exact zeros.
     """
-    S, = idx.shape
+    gather = idx is not None
     L, d = x.shape
+    S = idx.shape[0] if gather else L
     E, _, h = w1.shape
     dual = w2 is not None
-    bl = min(bl, max(S, 8))
-    bh = largest_divisor_tile(h, bh)
-    S_pad = ((S + bl - 1) // bl) * bl
-    idx_p = jnp.pad(idx.astype(jnp.int32), (0, S_pad - S))
-    n_tiles = S_pad // bl
-    nh = h // bh
-    wi_tile, wi_expert, wi_lo, wi_hi, wi_first, _ = make_work_items(
-        offsets.astype(jnp.int32), n_tiles, bl, E)
-    W = wi_tile.shape[0]
-
+    bl, S_pad = _row_tiles(S, bl)
+    bh = lane_tile(h, bh)
+    n_tiles, nh = S_pad // bl, h // bh
+    items = make_work_items(offsets, n_tiles, bl, E)
+    W = items[0].shape[0]
+    scalars = list(items[:5])
+    it = x.dtype.itemsize
+    if gather:
+        scalars.append(jnp.pad(jnp.clip(idx.astype(jnp.int32), 0, L - 1),
+                               (0, S_pad - S)))
+        xin = row_words(x)
+        x_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((bl, 1, xin.shape[-1]), xin.dtype),
+                   pltpu.SemaphoreType.DMA(())]
+        x_vmem = bl * d * it
+    else:
+        xin = jnp.pad(x, ((0, S_pad - S), (0, 0)))
+        x_spec = pl.BlockSpec((bl, d), lambda hh, wi, *s: (s[0][wi], 0))
+        scratch = []
+        x_vmem = 2 * bl * d * it
     n_out = 1 + (1 if save_ab else 0) + (1 if (save_ab and dual) else 0)
-    out_shape = [jax.ShapeDtypeStruct((S_pad, h), x.dtype)] * n_out
-    out_specs = [pl.BlockSpec((bl, bh), lambda wi, hh, *s: (tile_map(wi, s), hh))
-                 for _ in range(n_out)]
-
-    # index_map helpers get the scalar-prefetch refs appended.
-    def tile_map(wi, scalars):
-        return scalars[1][wi]          # wi_tile
-
-    def x_map(wi, hh, *scalars):
-        return (0, 0)
-
-    def w_map(wi, hh, *scalars):
-        return (scalars[2][wi], 0, hh)  # wi_expert
-
-    in_specs = [
-        pl.BlockSpec((L, d), x_map),
-        pl.BlockSpec((1, d, bh), w_map),
-    ]
-    args = [x, w1]
-    if dual:
-        in_specs.append(pl.BlockSpec((1, d, bh), w_map))
-        args.append(w2)
-
-    kernel = functools.partial(
-        _kernel, bl=bl, dual=dual, epilogue=epilogue and dual)
-
-    def body(*refs):
-        scalars = refs[:6]
-        if dual:
-            x_r, w1_r, w2_r = refs[6:9]
-            outs = refs[9:9 + n_out]
-            scratch = refs[9 + n_out]
-        else:
-            x_r, w1_r = refs[6:8]
-            w2_r = None
-            outs = refs[8:8 + n_out]
-            scratch = refs[8 + n_out]
-        y_r = outs[0]
-        a_r = outs[1] if save_ab else None
-        b_r = outs[2] if (save_ab and dual) else None
-        kernel(*scalars, x_r, w1_r, w2_r, y_r, a_r, b_r, scratch)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(W, nh),
-        in_specs=in_specs,
-        out_specs=out_specs if n_out > 1 else out_specs[0],
-        scratch_shapes=[pltpu.VMEM((bl, d), x.dtype)],
-    )
+    w_spec = pl.BlockSpec((1, d, bh), lambda hh, wi, *s: (s[1][wi], 0, hh))
+    out_spec = pl.BlockSpec((bl, bh), lambda hh, wi, *s: (s[0][wi], hh))
+    n_w = 2 if dual else 1
+    vmem = (x_vmem + 2 * n_w * d * bh * w1.dtype.itemsize
+            + 2 * n_out * bl * bh * it + 4 * bl * bh * 4 + bl * d * 4)
     out = pl.pallas_call(
-        body, grid_spec=grid_spec,
-        out_shape=out_shape if n_out > 1 else out_shape[0],
-        interpret=interpret,
-    )(idx_p, wi_tile, wi_expert, wi_lo, wi_hi, wi_first, *args)
+        functools.partial(_gmm_kernel, bl=bl, dual=dual,
+                          epilogue=epilogue and dual, n_out=n_out,
+                          gather=gather, dtype=x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(nh, W),
+            in_specs=[x_spec] + [w_spec] * n_w,
+            out_specs=[out_spec] * n_out,
+            scratch_shapes=scratch,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((S_pad, h), x.dtype)] * n_out,
+        compiler_params=compiler_params("gather_gmm", vmem),
+        interpret=kernels.interpret_mode(),
+    )(*scalars, xin, w1, *([w2] if dual else []))
     if n_out == 1:
-        return out[:S]
+        return out[0][:S]
     return tuple(o[:S] for o in out)
 
 
@@ -325,53 +274,40 @@ def gather_gmm(x: jax.Array, idx: jax.Array, offsets: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _onehot_select(idx_ref, lo, hi, n_rows: int, bl: int):
-    """(bl, n_rows) one-hot dispatch matrix for this work item: row r is
-    one-hot at token ``idx[r]`` when r lies in the item's [lo, hi) slot
-    range, all-zero otherwise.  Gather is ``sel @ x`` and scatter-accumulate
-    is ``selᵀ @ v`` — both MXU matmuls, no per-row dynamic slices (the
-    classic TPU dispatch idiom; exact in f32 since entries are 0/1 and each
-    row has at most one hit)."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bl, 1), 0)
-    active = (rows >= lo) & (rows < hi)
-    toks = jnp.where(active, idx_ref[...].astype(jnp.int32), -1)   # (bl, 1)
-    return (toks == jax.lax.broadcasted_iota(jnp.int32, (bl, n_rows), 1)
-            ).astype(jnp.float32)
-
-
-def _fused_kernel(tile_ref, expert_ref, lo_ref, hi_ref,
-                  idx_ref, x_ref, g_ref, w1_ref, w2_ref, w3_ref, y_ref,
-                  xt_ref, pacc_ref, *, bl: int, nh: int):
+def _fused_kernel(tile_ref, expert_ref, lo_ref, hi_ref, idx_ref,
+                  x_ref, g_ref, w1_ref, w2_ref, w3_ref, y0_ref, y_ref,
+                  xt_ref, pacc_ref, ybuf_ref, sem, *, bl: int, nh: int,
+                  dtype):
+    del expert_ref, y0_ref           # y0 is y's initial (aliased) buffer
     wi = pl.program_id(0)
     hh = pl.program_id(1)
     lo, hi = lo_ref[wi], hi_ref[wi]
-    sel = _onehot_select(idx_ref, lo, hi, y_ref.shape[0], bl)
+    base = tile_ref[wi] * bl
 
-    @pl.when((wi == 0) & (hh == 0))
-    def _init_out():
-        # The (L, d) accumulator is one persistent block: zero it once.
-        y_ref[...] = jnp.zeros_like(y_ref)
+    def tok(r):
+        return idx_ref[base + r]
+
+    def slot(r):
+        return r
 
     @pl.when(hh == 0)
     def _gather():
         # On-the-fly dispatch: this item's rows, gathered once per work item
         # (the scratch persists across the sequential hh steps).
-        xt_ref[...] = jax.lax.dot_general(
-            sel, x_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(xt_ref.dtype)
+        dma_rows(x_ref, xt_ref, sem, lo, hi, tok, slot)
 
-    xt = xt_ref[...]
+    xt = jnp.where(_row_mask(lo, hi, bl), rows_from_words(xt_ref[...], dtype),
+                   jnp.zeros((), dtype))
     a = jnp.dot(xt, w1_ref[0], preferred_element_type=jnp.float32)
     b = jnp.dot(xt, w2_ref[0], preferred_element_type=jnp.float32)
     y_swi = _silu(a) * b                       # (bl, bh), VMEM-only
     # Round to the I/O dtype at the GEMM boundary — the same place the
     # unfused path materializes y_swi — so fused-vs-unfused stays within
     # reduction-order noise even in bf16 (identity in f32).
-    y_swi = y_swi.astype(xt_ref.dtype).astype(jnp.float32)
+    y_swi = y_swi.astype(dtype).astype(jnp.float32)
     # Second grouped GEMM, this h-block's contribution: (bl, bh) @ (bh, d).
-    p = jax.lax.dot_general(y_swi, w3_ref[0].astype(jnp.float32),
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    p = jnp.dot(y_swi, w3_ref[0].astype(jnp.float32),
+                preferred_element_type=jnp.float32)
 
     @pl.when(hh == 0)
     def _p_init():
@@ -383,23 +319,21 @@ def _fused_kernel(tile_ref, expert_ref, lo_ref, hi_ref,
 
     @pl.when(hh == nh - 1)
     def _combine():
-        # Fused combine epilogue: once the h-contraction is complete,
-        # scatter-accumulate each slot's gated partial into y[token] through
-        # the same one-hot dispatch matrix the gather used (this is
-        # kernels/combine.py folded into the grid pass — no (L*k, d)
-        # partials buffer ever exists).  ``selᵀ @ gated`` routes slot r's
-        # partial to y[idx[r]]; inactive rows have an all-zero sel row.
+        # Fused combine epilogue: once the h-contraction is complete, add
+        # each slot's gated partial into y[token] in HBM (read the rows, add,
+        # write them back).  An item's slots are one expert's, so its tokens
+        # are distinct; the grid runs items one after another.
+        dma_rows(y_ref, ybuf_ref, sem, lo, hi, tok, slot)
         gated = g_ref[...].astype(jnp.float32) * pacc_ref[...]
-        y_ref[...] += jax.lax.dot_general(
-            sel, gated, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ybuf_ref[...] = ybuf_ref[...] + gated.reshape(ybuf_ref.shape)
+        dma_rows(ybuf_ref, y_ref, sem, lo, hi, slot, tok)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "bh", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl", "bh"))
 def fused_moe_fwd(x: jax.Array, g_slot: jax.Array, idx: jax.Array,
                   offsets: jax.Array, w1: jax.Array, w2: jax.Array,
-                  w3: jax.Array, *, bl: int = 128, bh: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  w3: jax.Array, *, bl: int = 128,
+                  bh: int = 128) -> jax.Array:
     """Fused dispatch→GEMM→combine SwiGLU MoE forward.
 
     One grid pass over the work items computes, per (row tile × expert ×
@@ -414,8 +348,8 @@ def fused_moe_fwd(x: jax.Array, g_slot: jax.Array, idx: jax.Array,
         scattered through ``token_index_map``).
       idx: (S,) ``expert_token_indices``; offsets: (E+1,) prefix sums.
       w1, w2: (E, d, h); w3: (E, h, d).
-      bl/bh: tile requests (``bh`` clamped to a divisor of ``h``); ask
-        ``repro.roofline.select_moe_tiles`` for hardware-informed sizes.
+      bl/bh: tile requests; ask ``repro.roofline.select_moe_tiles`` for
+        hardware-informed sizes.
 
     Returns the combined (L, d) output in fp32 (full-precision accumulation
     across h-blocks and the k slots; cast at the call site).
@@ -423,51 +357,45 @@ def fused_moe_fwd(x: jax.Array, g_slot: jax.Array, idx: jax.Array,
     S, = idx.shape
     L, d = x.shape
     E, _, h = w1.shape
-    bl = min(bl, max(S, 8))
-    bh = largest_divisor_tile(h, bh)
-    S_pad = ((S + bl - 1) // bl) * bl
-    idx_p = jnp.pad(idx.astype(jnp.int32), (0, S_pad - S))
-    g_pad = jnp.pad(g_slot, (0, S_pad - S)).reshape(S_pad, 1)
-    n_tiles = S_pad // bl
-    nh = h // bh
-    wi_tile, wi_expert, wi_lo, wi_hi, _, _ = make_work_items(
-        offsets.astype(jnp.int32), n_tiles, bl, E)
-    W = wi_tile.shape[0]
-
-    def x_map(wi, hh, *scalars):
-        return (0, 0)
-
-    def g_map(wi, hh, *scalars):
-        return (scalars[0][wi], 0)      # wi_tile
-
-    def w12_map(wi, hh, *scalars):
-        return (scalars[1][wi], 0, hh)  # wi_expert
-
-    def w3_map(wi, hh, *scalars):
-        return (scalars[1][wi], hh, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(W, nh),
-        in_specs=[
-            pl.BlockSpec((bl, 1), g_map),   # idx, tiled like the gates
-            pl.BlockSpec((L, d), x_map),
-            pl.BlockSpec((bl, 1), g_map),
-            pl.BlockSpec((1, d, bh), w12_map),
-            pl.BlockSpec((1, d, bh), w12_map),
-            pl.BlockSpec((1, bh, d), w3_map),
-        ],
-        out_specs=pl.BlockSpec((L, d), x_map),
-        scratch_shapes=[pltpu.VMEM((bl, d), x.dtype),
-                        pltpu.VMEM((bl, d), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_kernel, bl=bl, nh=nh),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((L, d), jnp.float32),
-        interpret=interpret,
-    )(wi_tile, wi_expert, wi_lo, wi_hi,
-      idx_p.reshape(S_pad, 1), x, g_pad, w1, w2, w3)
+    bl, S_pad = _row_tiles(S, bl)
+    bh = lane_tile(h, bh)
+    n_tiles, nh = S_pad // bl, h // bh
+    tile, expert, lo, hi, _, _ = make_work_items(offsets, n_tiles, bl, E)
+    W = tile.shape[0]
+    idx_p = jnp.pad(jnp.clip(idx.astype(jnp.int32), 0, L - 1), (0, S_pad - S))
+    g_pad = jnp.pad(g_slot.astype(jnp.float32), (0, S_pad - S)
+                    ).reshape(S_pad, 1)
+    xw = row_words(x)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    it, wit = x.dtype.itemsize, w1.dtype.itemsize
+    vmem = (bl * d * (it + 4 + 4) + 2 * 3 * d * bh * wit + 2 * bl * 128 * 4
+            + 3 * bl * bh * 4 + bl * d * 4)
+    y = pl.pallas_call(
+        functools.partial(_fused_kernel, bl=bl, nh=nh, dtype=x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(W, nh),
+            in_specs=[
+                any_spec,
+                pl.BlockSpec((bl, 1), lambda wi, hh, *s: (s[0][wi], 0)),
+                pl.BlockSpec((1, d, bh), lambda wi, hh, *s: (s[1][wi], 0, hh)),
+                pl.BlockSpec((1, d, bh), lambda wi, hh, *s: (s[1][wi], 0, hh)),
+                pl.BlockSpec((1, bh, d), lambda wi, hh, *s: (s[1][wi], hh, 0)),
+                any_spec,
+            ],
+            out_specs=any_spec,
+            scratch_shapes=[pltpu.VMEM((bl, 1, xw.shape[-1]), xw.dtype),
+                            pltpu.VMEM((bl, d), jnp.float32),
+                            pltpu.VMEM((bl, 1, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct((L, 1, d), jnp.float32),
+        input_output_aliases={10: 0},
+        compiler_params=compiler_params("fused_moe_fwd", vmem),
+        interpret=kernels.interpret_mode(),
+    )(tile, expert, lo, hi, idx_p, xw, g_pad, w1, w2, w3,
+      jnp.zeros((L, 1, d), jnp.float32))
+    return y.reshape(L, d)
 
 
 # ---------------------------------------------------------------------------
@@ -475,36 +403,33 @@ def fused_moe_fwd(x: jax.Array, g_slot: jax.Array, idx: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _fused_bwd_kernel(tile_ref, expert_ref, lo_ref, hi_ref,
-                      first_ref, efirst_ref,
-                      idx_ref, x_ref, dy_ref, g_ref, w1_ref, w2_ref, w3_ref,
+def _fused_bwd_kernel(tile_ref, expert_ref, lo_ref, hi_ref, first_ref,
+                      efirst_ref, idx_ref,
+                      x_ref, dy_ref, g_ref, w1_ref, w2_ref, w3_ref, dx0_ref,
                       dx_ref, dg_ref, dw1_ref, dw2_ref, dw3_ref,
-                      xt_ref, dyt_ref, dxacc_ref, *, bl: int, nh: int):
-    wi = pl.program_id(0)
-    hh = pl.program_id(1)
+                      xt_ref, dyt_ref, dxbuf_ref, sem, *, bl: int, dtype):
+    del expert_ref, dx0_ref          # dx0 is dx's initial (aliased) buffer
+    wi = pl.program_id(1)
     lo, hi = lo_ref[wi], hi_ref[wi]
     first = first_ref[wi] == 1
     efirst = efirst_ref[wi] == 1
-    sel = _onehot_select(idx_ref, lo, hi, dx_ref.shape[0], bl)
+    base = tile_ref[wi] * bl
 
-    @pl.when((wi == 0) & (hh == 0))
-    def _init_dx():
-        dx_ref[...] = jnp.zeros_like(dx_ref)
+    def tok(r):
+        return idx_ref[base + r]
 
-    @pl.when(hh == 0)
-    def _gather():
-        # Replay the dispatch gather for x AND expand the (L, d) output
-        # grads to this item's slots — neither buffer was saved.
-        rows_c = (((1,), (0,)), ((), ()))
-        xt_ref[...] = jax.lax.dot_general(
-            sel, x_ref[...].astype(jnp.float32), rows_c,
-            preferred_element_type=jnp.float32).astype(xt_ref.dtype)
-        dyt_ref[...] = jax.lax.dot_general(
-            sel, dy_ref[...].astype(jnp.float32), rows_c,
-            preferred_element_type=jnp.float32).astype(dyt_ref.dtype)
+    def slot(r):
+        return r
 
-    xt = xt_ref[...]
-    dyt = dyt_ref[...].astype(jnp.float32)
+    # Replay the dispatch gather for x AND expand the (L, d) output grads to
+    # this item's slots — neither buffer was saved.
+    dma_rows(x_ref, xt_ref, sem, lo, hi, tok, slot)
+    dma_rows(dy_ref, dyt_ref, sem, lo, hi, tok, slot)
+    mask = _row_mask(lo, hi, bl)
+    xt = jnp.where(mask, rows_from_words(xt_ref[...], dtype),
+                   jnp.zeros((), dtype))
+    dyt = jnp.where(mask, rows_from_words(dyt_ref[...], dtype),
+                    jnp.zeros((), dtype)).astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)               # (bl, 1)
     # Recompute A, B, SiLU for this h-block (Algorithm 1's smart checkpoint,
     # taken to its deepest point: nothing but x and the weights was saved).
@@ -513,149 +438,120 @@ def _fused_bwd_kernel(tile_ref, expert_ref, lo_ref, hi_ref,
     sa = _silu(a)
     # Recomputed y_swi and the cotangent dyu are rounded to the I/O dtype,
     # matching the buffers the unfused backward reads (identity in f32).
-    y_swi = (sa * b).astype(xt_ref.dtype).astype(jnp.float32)
+    y_swi = (sa * b).astype(dtype).astype(jnp.float32)
     # dY_swi through the transposed third GEMM: (bl, d) x (bh, d) -> (bl, bh)
     dyu = jax.lax.dot_general(dyt, w3_ref[0].astype(jnp.float32),
                               (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    dyu = dyu.astype(xt_ref.dtype).astype(jnp.float32)
+    dyu = dyu.astype(dtype).astype(jnp.float32)
     dy_swi = dyu * g
     da = dy_swi * b * _dsilu(a)
     db = dy_swi * sa
 
-    def acc(ref, val, init):
-        @pl.when(init)
-        def _init():
-            ref[...] = val.astype(ref.dtype)
-
-        @pl.when(jnp.logical_not(init))
-        def _acc():
-            ref[...] += val.astype(ref.dtype)
-
-    # dgates, in slot order: rows outside [lo, hi) contribute exact zeros
-    # (their xt/dyt rows are zeroed), so the per-tile block accumulates
-    # cleanly across the tile's items and the h-blocks.
-    acc(dg_ref, jnp.sum(y_swi * dyu, axis=1, keepdims=True),
-        first & (hh == 0))
+    # dgates, in slot order, this h-block's share (summed over h-blocks by
+    # the wrapper): rows outside [lo, hi) contribute exact zeros.
+    _accumulate(dg_ref, jnp.sum(y_swi * dyu, axis=1, keepdims=True)[None],
+                first)
     rows_t = (((0,), (0,)), ((), ()))
     xt32 = xt.astype(jnp.float32)
-    acc(dw1_ref, jax.lax.dot_general(
+    _accumulate(dw1_ref, jax.lax.dot_general(
         xt32, da, rows_t, preferred_element_type=jnp.float32)[None], efirst)
-    acc(dw2_ref, jax.lax.dot_general(
+    _accumulate(dw2_ref, jax.lax.dot_general(
         xt32, db, rows_t, preferred_element_type=jnp.float32)[None], efirst)
-    acc(dw3_ref, jax.lax.dot_general(
+    _accumulate(dw3_ref, jax.lax.dot_general(
         y_swi * g, dyt, rows_t, preferred_element_type=jnp.float32)[None],
         efirst)
 
-    # Token gradients: accumulate over h-blocks, scatter once per work item.
+    # Token gradients, this h-block's share: added into dx[token] in HBM.
     dxg = (jax.lax.dot_general(da, w1_ref[0].astype(jnp.float32),
                                (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
            + jax.lax.dot_general(db, w2_ref[0].astype(jnp.float32),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32))
-
-    @pl.when(hh == 0)
-    def _dx_init():
-        dxacc_ref[...] = dxg
-
-    @pl.when(hh > 0)
-    def _dx_acc():
-        dxacc_ref[...] += dxg
-
-    @pl.when(hh == nh - 1)
-    def _dx_scatter():
-        # selᵀ routes each slot's accumulated dx back to its token row
-        # (inactive rows have all-zero sel rows, so they contribute nothing).
-        dx_ref[...] += jax.lax.dot_general(
-            sel, dxacc_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    dma_rows(dx_ref, dxbuf_ref, sem, lo, hi, tok, slot)
+    dxbuf_ref[...] = dxbuf_ref[...] + dxg.reshape(dxbuf_ref.shape)
+    dma_rows(dxbuf_ref, dx_ref, sem, lo, hi, slot, tok)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "bh", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl", "bh"))
 def fused_moe_bwd(x: jax.Array, dy: jax.Array, g_slot: jax.Array,
                   idx: jax.Array, offsets: jax.Array, w1: jax.Array,
                   w2: jax.Array, w3: jax.Array, *, bl: int = 128,
-                  bh: int = 128, interpret: bool = True):
+                  bh: int = 128):
     """Backward of :func:`fused_moe_fwd` in one grid sweep.
 
     Replays the dispatch gather in-kernel (both ``x`` rows and the slot
     expansion of ``dy``), recomputes A/B/SiLU per h-block, and accumulates
     all five gradients — no ``(L·k, ·)`` buffer is read from or written to
-    HBM.  Empty experts' dw blocks and dead row tiles are zero-initialized
-    by the work-item fillers.
+    HBM.  The h-blocks are the outer grid axis, so each expert's
+    ``(d, bh)`` weight-gradient blocks are finished in one run of items;
+    ``dx`` collects each h-block's share by row DMA.  Empty experts' dw
+    blocks and dead row tiles are zero-initialized by the work items.
 
     Returns ``(dx (L, d), dgates_slot (S,), dw1, dw2, dw3)`` in fp32.
     """
     S, = idx.shape
     L, d = x.shape
     E, _, h = w1.shape
-    bl = min(bl, max(S, 8))
-    bh = largest_divisor_tile(h, bh)
-    S_pad = ((S + bl - 1) // bl) * bl
-    idx_p = jnp.pad(idx.astype(jnp.int32), (0, S_pad - S))
-    g_pad = jnp.pad(g_slot, (0, S_pad - S)).reshape(S_pad, 1)
-    n_tiles = S_pad // bl
-    nh = h // bh
-    wi_tile, wi_expert, wi_lo, wi_hi, wi_first, wi_efirst = make_work_items(
-        offsets.astype(jnp.int32), n_tiles, bl, E)
-    W = wi_tile.shape[0]
+    bl, S_pad = _row_tiles(S, bl)
+    bh = lane_tile(h, bh)
+    n_tiles, nh = S_pad // bl, h // bh
+    items = make_work_items(offsets, n_tiles, bl, E)
+    W = items[0].shape[0]
+    idx_p = jnp.pad(jnp.clip(idx.astype(jnp.int32), 0, L - 1), (0, S_pad - S))
+    g_pad = jnp.pad(g_slot.astype(jnp.float32), (0, S_pad - S)
+                    ).reshape(S_pad, 1)
+    xw, dyw = row_words(x), row_words(dy.astype(x.dtype))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
-    def full_map(wi, hh, *scalars):
-        return (0, 0)
+    def w12_map(hh, wi, *s):
+        return (s[1][wi], 0, hh)
 
-    def g_map(wi, hh, *scalars):
-        return (scalars[0][wi], 0)      # wi_tile
+    def w3_map(hh, wi, *s):
+        return (s[1][wi], hh, 0)
 
-    def w12_map(wi, hh, *scalars):
-        return (scalars[1][wi], 0, hh)  # wi_expert
-
-    def w3_map(wi, hh, *scalars):
-        return (scalars[1][wi], hh, 0)
-
-    def dw12_map(wi, hh, *scalars):
-        return (scalars[1][wi], 0, hh)
-
-    def dw3_map(wi, hh, *scalars):
-        return (scalars[1][wi], hh, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(W, nh),
-        in_specs=[
-            pl.BlockSpec((bl, 1), g_map),   # idx, tiled like the gates
-            pl.BlockSpec((L, d), full_map),
-            pl.BlockSpec((L, d), full_map),
-            pl.BlockSpec((bl, 1), g_map),
-            pl.BlockSpec((1, d, bh), w12_map),
-            pl.BlockSpec((1, d, bh), w12_map),
-            pl.BlockSpec((1, bh, d), w3_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((L, d), full_map),
-            pl.BlockSpec((bl, 1), g_map),
-            pl.BlockSpec((1, d, bh), dw12_map),
-            pl.BlockSpec((1, d, bh), dw12_map),
-            pl.BlockSpec((1, bh, d), dw3_map),
-        ],
-        scratch_shapes=[pltpu.VMEM((bl, d), x.dtype),
-                        pltpu.VMEM((bl, d), dy.dtype),
-                        pltpu.VMEM((bl, d), jnp.float32)],
-    )
+    it, wit = x.dtype.itemsize, w1.dtype.itemsize
+    vmem = (bl * d * (2 * it + 4) + 2 * 3 * d * bh * (wit + 4)
+            + 4 * bl * 128 * 4 + 6 * bl * bh * 4 + 2 * bl * d * 4)
     dx, dg, dw1, dw2, dw3 = pl.pallas_call(
-        functools.partial(_fused_bwd_kernel, bl=bl, nh=nh),
-        grid_spec=grid_spec,
+        functools.partial(_fused_bwd_kernel, bl=bl, dtype=x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(nh, W),
+            in_specs=[
+                any_spec, any_spec,
+                pl.BlockSpec((bl, 1), lambda hh, wi, *s: (s[0][wi], 0)),
+                pl.BlockSpec((1, d, bh), w12_map),
+                pl.BlockSpec((1, d, bh), w12_map),
+                pl.BlockSpec((1, bh, d), w3_map),
+                any_spec,
+            ],
+            out_specs=[
+                any_spec,
+                pl.BlockSpec((1, bl, 1), lambda hh, wi, *s: (hh, s[0][wi], 0)),
+                pl.BlockSpec((1, d, bh), w12_map),
+                pl.BlockSpec((1, d, bh), w12_map),
+                pl.BlockSpec((1, bh, d), w3_map),
+            ],
+            scratch_shapes=[pltpu.VMEM((bl, 1, xw.shape[-1]), xw.dtype),
+                            pltpu.VMEM((bl, 1, dyw.shape[-1]), dyw.dtype),
+                            pltpu.VMEM((bl, 1, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((L, d), jnp.float32),
-            jax.ShapeDtypeStruct((S_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((nh, S_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((E, d, h), jnp.float32),
             jax.ShapeDtypeStruct((E, d, h), jnp.float32),
             jax.ShapeDtypeStruct((E, h, d), jnp.float32),
         ],
-        interpret=interpret,
-    )(wi_tile, wi_expert, wi_lo, wi_hi, wi_first, wi_efirst,
-      idx_p.reshape(S_pad, 1), x, dy, g_pad, w1, w2, w3)
-    return dx, dg[:S, 0], dw1, dw2, dw3
+        input_output_aliases={13: 0},
+        compiler_params=compiler_params("fused_moe_bwd", vmem),
+        interpret=kernels.interpret_mode(),
+    )(*items, idx_p, xw, dyw, g_pad, w1, w2, w3,
+      jnp.zeros((L, 1, d), jnp.float32))
+    return dx.reshape(L, d), dg.sum(axis=0)[:S, 0], dw1, dw2, dw3
 
 
 # ---------------------------------------------------------------------------
@@ -663,22 +559,32 @@ def fused_moe_bwd(x: jax.Array, dy: jax.Array, g_slot: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _gather_rows_kernel(rows_ref, src_ref, out_ref, *, bl: int):
+def _gather_rows_kernel(rows_ref, ids_ref, src_ref, out_ref, buf_ref, sem,
+                        *, bl: int, dtype):
     t = pl.program_id(0)
 
-    def row(r, _):
-        rid = rows_ref[t * bl + r]
-        active = rid >= 0
-        src = pl.load(src_ref, (pl.ds(jnp.maximum(rid, 0), 1), slice(None)))
-        out_ref[pl.ds(r, 1), :] = jnp.where(active, src, 0)
-        return 0
+    def each_valid(fn):
+        def body(r, c):
+            rid = rows_ref[t * bl + r]
 
-    jax.lax.fori_loop(0, bl, row, 0, unroll=False)
+            @pl.when(rid >= 0)
+            def _():
+                fn(r, rid)
+            return c
+        jax.lax.fori_loop(0, bl, body, 0)
+
+    each_valid(lambda r, rid: pltpu.make_async_copy(
+        src_ref.at[pl.ds(rid, 1)], buf_ref.at[pl.ds(r, 1)], sem).start())
+    each_valid(lambda r, rid: pltpu.make_async_copy(
+        src_ref.at[pl.ds(0, 1)], buf_ref.at[pl.ds(0, 1)], sem).wait())
+    out_ref[...] = jnp.where(ids_ref[...] >= 0,
+                             rows_from_words(buf_ref[...], dtype),
+                             jnp.zeros((), dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "interpret"))
-def gather_rows_pallas(src: jax.Array, row_ids: jax.Array, *, bl: int = 128,
-                       interpret: bool = True) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("bl",))
+def gather_rows_pallas(src: jax.Array, row_ids: jax.Array, *,
+                       bl: int = 128) -> jax.Array:
     """Build an (N, d) row buffer straight from ``src`` rows: ``out[i] =
     src[row_ids[i]]``, with ``row_ids[i] < 0`` producing an exact zero row.
 
@@ -688,23 +594,27 @@ def gather_rows_pallas(src: jax.Array, row_ids: jax.Array, *, bl: int = 128,
     """
     N, = row_ids.shape
     L, d = src.shape
-    bl = min(bl, max(N, 8))
-    N_pad = ((N + bl - 1) // bl) * bl
-    rows_p = jnp.pad(row_ids.astype(jnp.int32), (0, N_pad - N),
-                     constant_values=-1)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(N_pad // bl,),
-        in_specs=[pl.BlockSpec((L, d), lambda t, *s: (0, 0))],
-        out_specs=pl.BlockSpec((bl, d), lambda t, *s: (t, 0)),
-    )
+    bl, N_pad = _row_tiles(N, bl)
+    rows_p = jnp.pad(jnp.minimum(row_ids.astype(jnp.int32), L - 1),
+                     (0, N_pad - N), constant_values=-1)
+    sw = row_words(src)
+    it = src.dtype.itemsize
     out = pl.pallas_call(
-        functools.partial(_gather_rows_kernel, bl=bl),
-        grid_spec=grid_spec,
+        functools.partial(_gather_rows_kernel, bl=bl, dtype=src.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N_pad // bl,),
+            in_specs=[pl.BlockSpec((bl, 1), lambda t, *s: (t, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((bl, d), lambda t, *s: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((bl, 1, sw.shape[-1]), sw.dtype),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
         out_shape=jax.ShapeDtypeStruct((N_pad, d), src.dtype),
-        interpret=interpret,
-    )(rows_p, src)
+        compiler_params=compiler_params(
+            "gather_rows_pallas", bl * d * (it + 2 * it) + 2 * bl * 128 * 4),
+        interpret=kernels.interpret_mode(),
+    )(rows_p, rows_p.reshape(N_pad, 1), sw)
     return out[:N]
 
 
@@ -715,74 +625,62 @@ def gather_rows_pallas(src: jax.Array, row_ids: jax.Array, *, bl: int = 128,
 
 def _dw_kernel(tile_ref, expert_ref, lo_ref, hi_ref, efirst_ref,
                x_ref, g_ref, dw_ref, *, bl: int):
-    wi = pl.program_id(0)
-    lo, hi = lo_ref[wi], hi_ref[wi]
-    first = efirst_ref[wi] == 1
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bl, 1), 0)
-    mask = (rows >= lo) & (rows < hi)
+    del tile_ref, expert_ref
+    wi = pl.program_id(2)
+    mask = _row_mask(lo_ref[wi], hi_ref[wi], bl)
     xt = jnp.where(mask, x_ref[...], 0).astype(jnp.float32)
-    # Contract the row axis: (bl, d), (bl, h) -> (d, h).  Rows outside this
-    # item's range are zeroed in xt, so the full-tile dot is exact.
+    # Contract the row axis: (bl, bd), (bl, bh) -> (bd, bh).  Rows outside
+    # this item's range are zeroed in xt, so the full-tile dot is exact.
     dwt = jax.lax.dot_general(xt, g_ref[...].astype(jnp.float32),
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-
-    @pl.when(first)
-    def _init():
-        dw_ref[...] = dwt[None].astype(dw_ref.dtype)
-
-    @pl.when(jnp.logical_not(first))
-    def _acc():
-        dw_ref[...] += dwt[None].astype(dw_ref.dtype)
+    _accumulate(dw_ref, dwt[None], efirst_ref[wi] == 1)
 
 
-@functools.partial(jax.jit, static_argnames=("bl", "interpret"))
+@functools.partial(jax.jit, static_argnames=("bl", "bd", "bh"))
 def gmm_dw_pallas(lhs: jax.Array, dout: jax.Array, offsets: jax.Array,
-                  *, bl: int = 128, interpret: bool = True) -> jax.Array:
+                  *, bl: int = 128, bd: int = 512,
+                  bh: int = 512) -> jax.Array:
     """Per-group weight gradient (S, d), (S, h) -> (E, d, h) on the
     work-item grid.
 
     ``lhs``/``dout`` rows are already in expert order; each work item masks
     its expert's row range inside the tile and accumulates ``x_tile^T @
-    dout_tile`` into ``dw[expert]``.  An expert's work items are consecutive
-    in the tile-major item order (its row segment is contiguous), so the
-    output block is only ever revisited on adjacent grid steps — the
-    accumulation pattern TPU grids require.  Cross-tile partials genuinely
-    overlap (unlike the forward's disjoint row ranges), so the output is
-    fp32 and cast to ``lhs.dtype`` only at the end — the backend contract's
-    fp32 accumulation.  Blocks of *empty* experts are zero-initialized
-    in-kernel (each empty expert gets a dedicated ``efirst`` filler item) —
-    callers no longer need to mask the output.
+    dout_tile`` into the ``(bd, bh)`` block of ``dw[expert]``.  The grid is
+    ``(d-blocks, h-blocks, items)``: an expert's items are consecutive, so
+    each output block is finished in one run (the accumulation pattern TPU
+    grids require).  Cross-tile partials genuinely overlap (unlike the
+    forward's disjoint row ranges), so the output is fp32 and cast to
+    ``lhs.dtype`` only at the end — the backend contract's fp32
+    accumulation.  Blocks of *empty* experts are zero-initialized in-kernel.
     """
     S, d = lhs.shape
     h = dout.shape[1]
     E = offsets.shape[0] - 1
-    bl = min(bl, max(S, 8))
-    S_pad = ((S + bl - 1) // bl) * bl
+    bl, S_pad = _row_tiles(S, bl)
+    bd, bh = lane_tile(d, bd), lane_tile(h, bh)
     lhs_p = jnp.pad(lhs, ((0, S_pad - S), (0, 0)))
     dout_p = jnp.pad(dout, ((0, S_pad - S), (0, 0)))
-    n_tiles = S_pad // bl
-    wi_tile, wi_expert, wi_lo, wi_hi, _, wi_efirst = make_work_items(
-        offsets.astype(jnp.int32), n_tiles, bl, E)
-    W = wi_tile.shape[0]
-
-    def row_map(wi, *scalars):
-        return (scalars[0][wi], 0)       # wi_tile
-
-    def dw_map(wi, *scalars):
-        return (scalars[1][wi], 0, 0)    # wi_expert
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(W,),
-        in_specs=[pl.BlockSpec((bl, d), row_map),
-                  pl.BlockSpec((bl, h), row_map)],
-        out_specs=pl.BlockSpec((1, d, h), dw_map),
-    )
+    tile, expert, lo, hi, _, efirst = make_work_items(
+        offsets, S_pad // bl, bl, E)
+    W = tile.shape[0]
+    it = lhs.dtype.itemsize
+    vmem = (2 * bl * (bd + bh) * it + 2 * bd * bh * 4
+            + bl * (bd + bh) * 4 + bd * bh * 4)
     out = pl.pallas_call(
         functools.partial(_dw_kernel, bl=bl),
-        grid_spec=grid_spec,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(d // bd, h // bh, W),
+            in_specs=[
+                pl.BlockSpec((bl, bd), lambda dd, hh, wi, *s: (s[0][wi], dd)),
+                pl.BlockSpec((bl, bh), lambda dd, hh, wi, *s: (s[0][wi], hh)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, bd, bh), lambda dd, hh, wi, *s: (s[1][wi], dd, hh)),
+        ),
         out_shape=jax.ShapeDtypeStruct((E, d, h), jnp.float32),
-        interpret=interpret,
-    )(wi_tile, wi_expert, wi_lo, wi_hi, wi_efirst, lhs_p, dout_p)
+        compiler_params=compiler_params("gmm_dw_pallas", vmem),
+        interpret=kernels.interpret_mode(),
+    )(tile, expert, lo, hi, efirst, lhs_p, dout_p)
     return out.astype(lhs.dtype)

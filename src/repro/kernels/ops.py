@@ -1,8 +1,8 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to True (this container is CPU-only; the kernels target
-TPU and are validated in interpret mode against ``ref.py``).  On TPU, call
-with ``interpret=False``.
+Whether a kernel is compiled or interpreted is decided from the platform, in
+one place (:func:`repro.kernels.interpret_mode`): compiled by Mosaic on a TPU,
+interpreted on the CPU, where the tests check each kernel against ``ref.py``.
 
 ``moe_ffn_blaze_pallas`` composes the kernels into the full MoEBlaze expert
 layer — dispatch build, gather-GMM with fused SwiGLU epilogue, second grouped
@@ -82,12 +82,10 @@ def _moe_pallas(backend, x, w1, w2, w3, gates, eti, off, tim, lens):
 
 
 def _moe_pallas_fwd(backend, x, w1, w2, w3, gates, eti, off, tim, lens):
-    S = eti.shape[0]
     # Fused gather + dual GEMM + SwiGLU epilogue (paper §5.2 kernel).
     y_swi, a, b = gather_gmm(x, eti, off, w1, w2, save_ab=True)
     # Second grouped GEMM (identity gather: rows already in expert order).
-    p_out = gather_gmm(y_swi, jnp.arange(S, dtype=jnp.int32), off, w3,
-                       epilogue=False)
+    p_out = gather_gmm(y_swi, None, off, w3, epilogue=False)
     y = combine(p_out, tim, gates)
     return y, (x, w1, w2, w3, gates, eti, off, tim, lens, a, b, y_swi)
 
@@ -96,7 +94,6 @@ def _moe_pallas_bwd(backend, res, dy):
     (x, w1, w2, w3, gates, eti, off, tim, lens, a, b, y_swi) = res
     L, k = tim.shape
     S = eti.shape[0]
-    ident = jnp.arange(S, dtype=jnp.int32)
     g_slot = jnp.zeros((S,), gates.dtype).at[tim.reshape(-1)].set(
         gates.reshape(-1))
     # Expand output grads to slots (gather through the index metadata).
@@ -104,7 +101,7 @@ def _moe_pallas_bwd(backend, res, dy):
     # dW3 / dY_swi via grouped GEMMs (gather_gmm with identity index).
     dw3 = gmm_dw(y_swi * g_slot[:, None].astype(y_swi.dtype), dyg, lens,
                  backend=backend)
-    dyu = gather_gmm(dyg, ident, off, jnp.swapaxes(w3, 1, 2), epilogue=False)
+    dyu = gather_gmm(dyg, None, off, jnp.swapaxes(w3, 1, 2), epilogue=False)
     dgates = jnp.take(jnp.sum(y_swi * dyu, -1),
                       tim.reshape(-1)).reshape(gates.shape).astype(gates.dtype)
     dy_swi = dyu * g_slot[:, None].astype(dyu.dtype)

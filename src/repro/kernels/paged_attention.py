@@ -20,11 +20,10 @@ the kernel loads the int8 page plus its f16 per-vector scales, multiplies
 scores by ``k_scale`` rows and probabilities by ``v_scale`` rows, and never
 dequantizes storage.
 
-Validated in interpret mode against ``paged_cache.paged_gather_attention``
-on CPU across {f32, bf16, int8} x {window, softcap}; on a real TPU the same
-grid lowers natively (align ``page_size`` / ``Dh`` to the (8, 128) f32 /
-(32, 128) int8 tile floors there — serving configs use Dh >= 64 and
-page_size >= 16, test configs run interpret mode only).
+Checked against ``paged_cache.paged_gather_attention`` in interpret mode on
+the CPU across {f32, bf16, int8} x {window, softcap}; compiled for a TPU the
+block shapes must meet the (8, 128) f32 / (32, 128) int8 tile floors
+(serving configs use Dh >= 64 and page_size >= 16).
 """
 
 from __future__ import annotations
@@ -35,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro import kernels
 
 NEG_INF = -1e30
 TRASH_PAGE = 0
@@ -93,13 +94,12 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *refs,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("window", "cap", "interpret"))
+                   static_argnames=("window", "cap"))
 def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, k_scale: jax.Array | None,
                            v_scale: jax.Array | None, page_table: jax.Array,
                            positions: jax.Array, *, window: int = 0,
-                           cap: float = 0.0,
-                           interpret: bool = True) -> jax.Array:
+                           cap: float = 0.0) -> jax.Array:
     """q: (B, 1, Hq, Dh); pools: (P, page_size, Hkv, Dh) (+ f16 scales
     ``(P, page_size, Hkv, 1)`` when int8); page_table: (B, pages_per_seq);
     positions: (B,) current written position per request.
@@ -145,6 +145,6 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dh), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(page_table.astype(jnp.int32), positions.astype(jnp.int32), *inputs)
     return out.reshape(B, 1, Hq, Dh)

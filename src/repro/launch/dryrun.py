@@ -337,7 +337,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             # the 1-group one, which would extrapolate below zero
             return max(0.0, M * (b[key] + (G - 1) * (c[key] - b[key])))
 
-        from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+        from repro.roofline import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
         rec["flops_per_dev"] = extrap("flops_per_dev")
         rec["hlo_bytes_per_dev"] = extrap("hlo_bytes_per_dev")
         rec["collective_bytes"] = extrap("collective_bytes")

@@ -3,7 +3,14 @@ touches jax device state (the dry-run sets XLA_FLAGS before first init)."""
 
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis Auto-sharded (the compiler
+    partitions; shard_map bodies take their axes explicitly)."""
+    types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,18 +34,3 @@ def make_node_mesh(data: int = 1, node: int = 1, model: int = 1):
     intra-node hop over 'model' and its single cross-node hop over 'node'.
     """
     return make_mesh((data, node, model), ("data", "node", "model"))
-
-
-# TPU v5e hardware constants used by the roofline analysis (per chip).
-PEAK_FLOPS_BF16 = 197e12          # FLOP/s
-HBM_BW = 819e9                    # B/s
-ICI_BW_PER_LINK = 50e9            # B/s  (~ per link)
-DCN_BW = 12.5e9                   # B/s  cross-node (per-host data-center NIC)
-HBM_BYTES = 16 * 1024 ** 3        # 16 GiB per chip
-
-
-def axis_bandwidth(axis: str) -> float:
-    """Bytes/s the collective cost model charges for traffic over ``axis``:
-    'node'/'pod' cross the data-center network, everything else rides the
-    intra-node interconnect."""
-    return DCN_BW if axis in ("node", "pod") else ICI_BW_PER_LINK

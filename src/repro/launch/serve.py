@@ -22,6 +22,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.models import transformer as T
 from repro.serve.engine import Request, ServeEngine
 from repro.train.checkpointing import restore_checkpoint
@@ -53,6 +54,7 @@ def main(argv=None):
     ap.add_argument("--out", default=None, help="append the JSON run record "
                                                 "here instead of stdout")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

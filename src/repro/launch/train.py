@@ -1,11 +1,15 @@
 """Distributed training launcher.
 
-On a real TPU slice this builds the production mesh, shards params/optimizer
-FSDP x TP per `repro.sharding`, and runs the training loop.  On this CPU
-container it runs with a debug mesh over host devices (or single device):
+On a TPU slice this builds the production mesh, shards params/optimizer
+FSDP x TP per `repro.sharding`, and runs the training loop.  ``--mesh DxM``
+lays a debug mesh over the local devices (TPU chips, or host CPU devices,
+which the flag creates):
 
     PYTHONPATH=src python -m repro.launch.train --arch mixtral-8x7b \
         --reduced --steps 20 --mesh 2x4
+
+The persistent compilation cache goes where ``JAX_COMPILATION_CACHE_DIR``
+says, else to ``.jax_cache/`` at the repository root.
 
     # production (TPU pod):
     python -m repro.launch.train --arch qwen3-moe-30b-a3b --production-mesh
@@ -35,6 +39,7 @@ from repro import sharding as shd
 from repro.configs import get_config
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import make_batch_iterator
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import transformer as T
 from repro.train import checkpointing
@@ -58,6 +63,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import gmm_backend as GB
 from repro.core import routing
 from repro.core.baseline import moe_ffn_dense, moe_ffn_megablocks
@@ -627,11 +626,11 @@ def moe_sublayer(x: jax.Array, p: dict, cfg, *, mesh=None,
         overflow = jax.lax.pmean(overflow, all_axes)
         return y.reshape(Bl, Sl, d), aux, overflow
 
-    y, aux, overflow = shard_map(
+    y, aux, overflow = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, p_specs),
         out_specs=(x_spec, P(), P()),
-        check=False,
+        check_vma=False,
     )(x, p)
     if with_stats:
         return y, aux, {"a2a_overflow": overflow}

@@ -22,8 +22,23 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-from repro.launch.mesh import (HBM_BW, HBM_BYTES, ICI_BW_PER_LINK,
-                               PEAK_FLOPS_BF16, axis_bandwidth)
+from repro.hardware import DCN_BW, peaks
+
+# The modeled chip (TPU v5e): every analytic term below is charged at its
+# peaks, from the one table in ``repro.hardware``.
+_CHIP = peaks()
+PEAK_FLOPS_BF16 = _CHIP.bf16_flops
+HBM_BW = _CHIP.hbm_bw
+HBM_BYTES = _CHIP.hbm_bytes
+ICI_BW_PER_LINK = _CHIP.ici_bw_per_link
+
+
+def axis_bandwidth(axis: str) -> float:
+    """Bytes/s the collective cost model charges for traffic over ``axis``:
+    'node'/'pod' cross the data-center network, everything else rides the
+    chip-to-chip interconnect (one link)."""
+    return DCN_BW if axis in ("node", "pod") else ICI_BW_PER_LINK
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,

@@ -589,18 +589,18 @@ class _GroupScheduler:
             return None
         frozen = [s for s in range(eng.slots)
                   if self.owner[s] is not None and s not in live]
-        lens_step = self.lengths
-        pt_step = self.page_table
-        if frozen:
-            lens_step = lens_step.copy()
-            pt_step = pt_step.copy()
-            for s in frozen:
-                lens_step[s] = 0
-                pt_step[s] = PC.TRASH_PAGE
-        gidx = self.gen_count
+        # The step reads copies: a host array handed to the device may be
+        # read until its transfer completes (zero-copy on the CPU), and
+        # lengths / gen_count change right below, before the step has run.
+        lens_step = self.lengths.copy()
+        pt_step = self.page_table.copy()
+        for s in frozen:
+            lens_step[s] = 0
+            pt_step[s] = PC.TRASH_PAGE
         toks, eng._cache = self.decode_fn(
             eng.params, eng._cache, self.last_tok, jnp.asarray(lens_step),
-            jnp.asarray(pt_step), jnp.asarray(self.rid), jnp.asarray(gidx))
+            jnp.asarray(pt_step), jnp.asarray(self.rid.copy()),
+            jnp.asarray(self.gen_count.copy()))
         self.last_tok = toks[:, None]
         eng.stats["decode_steps"] += 1
         eng.stats["decode_slot_tokens"] += len(live)

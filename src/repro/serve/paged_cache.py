@@ -37,7 +37,7 @@ jnp gather reference below — and ``pallas`` —
 ``kernels/paged_attention.py``, which walks the page table inside the
 kernel via scalar prefetch and reads only pages up to each request's
 position.  ``resolve_paged_attn`` applies the arg > env (``REPRO_PAGED_ATTN``)
-> auto chain; ``pallas`` is never auto-selected (interpret mode on CPU).
+> auto chain; ``pallas`` is never auto-selected.
 """
 
 from __future__ import annotations
@@ -279,7 +279,8 @@ class DensePagedAttn:
 class PallasPagedAttn:
     """``kernels/paged_attention.py``: page-table walk via scalar prefetch,
     online softmax across page steps, f32 accumulate, int8 scale-on-scores.
-    ``interpret=True`` on CPU; never auto-selected (explicit opt-in)."""
+    Compiled on a TPU, interpreted on the CPU; never auto-selected (no chip
+    measurement has shown it faster yet)."""
 
     name = "pallas"
 
@@ -295,8 +296,8 @@ class PallasPagedAttn:
 _ATTN_REGISTRY: dict[str, object] = {
     b.name: b for b in (DensePagedAttn, PallasPagedAttn)
 }
-#: auto order: the XLA gather path only — ``pallas`` is interpret-mode slow
-#: on CPU and exists as an explicitly requested kernel-validation target.
+#: auto order: the XLA gather path only — ``pallas`` is requested explicitly
+#: until a chip measurement shows it faster.
 _ATTN_AUTO = ("dense",)
 
 

@@ -1,13 +1,10 @@
 """Cross-backend parity test matrix (the proof behind context-scoped backend
-resolution): every backend reported by ``available_backends()`` must agree —
+resolution): every backend reported by ``backend_names()`` must agree —
 forward AND gradients — with the portable ``segment`` oracle through every
 MoE entry point ({moe_layer, baseline, moe_block}) in both f32 and bf16.
 
-Backends that the running JAX lacks (``ragged`` on 0.4.37, which ships
-``ragged_dot`` but not ``ragged_dot_general``) appear as *skips*, not
-absences, so the matrix shape is identical on every CI leg.  Shape variety
-(ragged group boundaries, empty experts, k=1 vs k=2) comes from
-hypothesis-drawn examples — ``tests/hypothesis_fallback.py`` keeps those
+Shape variety (ragged group boundaries, empty experts, k=1 vs k=2) comes
+from hypothesis-drawn examples — ``tests/hypothesis_fallback.py`` keeps those
 deterministic when hypothesis is not installed.
 """
 
@@ -29,7 +26,6 @@ from repro.core.routing import build_dispatch, top_k_gating
 from repro.models.moe_block import init_moe_params, moe_sublayer
 
 ALL_BACKENDS = GB.backend_names()
-AVAILABLE = GB.available_backends()
 
 LAYERS = ("moe_layer", "baseline", "moe_block")
 DTYPES = ("float32", "bfloat16")
@@ -38,13 +34,6 @@ DTYPES = ("float32", "bfloat16")
 # backends may order their fp32 reductions differently before that rounding.
 _TOL = {"float32": dict(rtol=1e-4, atol=1e-5),
         "bfloat16": dict(rtol=5e-2, atol=5e-2)}
-
-
-def _param(backends):
-    return [pytest.param(b, marks=() if b in AVAILABLE else
-                         pytest.mark.skip(reason=f"{b} unavailable on "
-                                          f"jax {jax.__version__}"))
-            for b in backends]
 
 
 def _moe_cfg(dtype="float32", E=4, k=2) -> ModelConfig:
@@ -103,7 +92,7 @@ def _layer_loss(layer, dtype, seed=11, L=40, E=4, k=2):
 
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("layer", LAYERS)
-@pytest.mark.parametrize("backend", _param(ALL_BACKENDS))
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
 def test_forward_and_grad_parity(backend, layer, dtype):
     """The matrix cell: value and every input/parameter gradient of ``layer``
     under ``backend`` match the ``segment`` oracle at ``dtype`` tolerance."""
@@ -144,14 +133,14 @@ def test_forward_parity_drawn_shapes(L, E, k):
         loss_fn, args = _layer_loss(layer, "float32", seed=100 + L,
                                     L=L, E=E, k=k)
         ref = float(loss_fn("segment")(*args))
-        for backend in AVAILABLE:
+        for backend in ALL_BACKENDS:
             got = float(loss_fn(backend)(*args))
             np.testing.assert_allclose(
                 got, ref, rtol=1e-4,
                 err_msg=f"{layer}/{backend} at L={L} E={E} k={k}")
 
 
-@pytest.mark.parametrize("backend", _param(ALL_BACKENDS))
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
 def test_gmm_primitive_parity_bf16(backend):
     """The raw gmm/gmm_dw primitives at bf16 with a ragged (empty-group)
     split: fp32 accumulation means every backend lands within bf16 rounding

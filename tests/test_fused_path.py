@@ -9,23 +9,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.core import gmm_backend as GB
+from repro.core.checkpoint import saved_residuals
 from repro.core.moe_layer import RESIDUAL_MODES, moe_ffn_blaze
 from repro.core.routing import build_dispatch, top_k_gating
+from repro.kernels.common import lane_tile, largest_divisor_tile
 from repro.kernels.gather_gmm import (fused_moe_fwd, gather_gmm,
                                       gather_rows_pallas, gmm_dw_pallas,
-                                      largest_divisor_tile, make_work_items)
+                                      make_work_items)
 
-AVAILABLE = GB.available_backends()
 UNFUSED = [b for b in GB.backend_names() if b != "pallas_fused"]
-
-
-def _param(backends):
-    return [pytest.param(b, marks=() if b in AVAILABLE else
-                         pytest.mark.skip(reason=f"{b} unavailable on "
-                                          f"jax {jax.__version__}"))
-            for b in backends]
 
 
 def _tol(dtype):
@@ -59,7 +52,7 @@ def _setup(seed, L, d, h, E, k, dtype=jnp.float32, biased=False):
 @pytest.mark.parametrize("residuals", sorted(RESIDUAL_MODES))
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("backend", _param(UNFUSED))
+@pytest.mark.parametrize("backend", UNFUSED)
 def test_fused_vs_unfused_parity(backend, dtype, residuals):
     """The fused kernel pair must be value- and gradient-exact (to dtype
     tolerance) against the unfused layer in *every* residual mode — the
@@ -134,7 +127,7 @@ def test_fused_saves_no_slot_buffers():
         def f(x, w1, w2, w3, gates):
             return moe_ffn_blaze(x, gates, disp, w1, w3, w2, backend=be)
         n = 0
-        for aval, src in compat.saved_residuals(f, x, w1, w2, w3, gates):
+        for aval, src in saved_residuals(f, x, w1, w2, w3, gates):
             if "from the argument" in str(src):
                 continue
             if getattr(aval, "shape", None) in ((S, h), (S, d)):
@@ -160,7 +153,11 @@ def test_largest_divisor_tile():
 
 def test_gather_gmm_non_divisible_h():
     """Regression: ``assert h % bh == 0`` used to crash any FFN width that
-    wasn't a multiple of the 128 tile request."""
+    wasn't a multiple of the 128 tile request.  Such a width is one
+    full-width block (``lane_tile``); 128-multiples tile by divisors."""
+    assert lane_tile(192, 128) == 192
+    assert lane_tile(4096, 512) == 512
+    assert lane_tile(384, 256) == 128
     L, d, h, E, k = 40, 16, 192, 4, 2
     x, w1, w2, w3, gates, disp = _setup(6, L, d, h, E, k)
     y = gather_gmm(x, disp.expert_token_indices, disp.expert_token_offsets,
@@ -193,8 +190,8 @@ def test_gmm_dw_pallas_zeros_empty_experts_in_kernel():
 def test_make_work_items_all_empty():
     """Regression: ``n_valid == 0`` (an ``ep_a2a`` shard whose tokens were
     all dropped) used to produce self-referential filler metadata and leave
-    every output block uninitialized.  Now: one ``first`` filler per tile,
-    one ``efirst`` filler per expert, all ranges empty."""
+    every output block uninitialized.  Now: every tile and every expert
+    opens a run (first / efirst), all ranges empty."""
     n_tiles, E, bl = 3, 4, 32
     off = jnp.zeros((E + 1,), jnp.int32)
     tile, expert, lo, hi, first, efirst = make_work_items(off, n_tiles, bl, E)
@@ -210,6 +207,37 @@ def test_make_work_items_all_empty():
     # metadata stays in range (no self-referential garbage)
     assert ((tile >= 0) & (tile < n_tiles)).all()
     assert ((expert >= 0) & (expert < E)).all()
+
+
+@pytest.mark.parametrize("sizes,n_tiles,bl", [
+    ([30, 0, 34, 0], 2, 32),            # boundaries mid-tile, empty experts
+    ([0, 0, 64, 0, 5], 3, 32),          # leading empties, dead tail tiles
+    ([32, 32, 0, 32], 3, 32),           # boundaries on tile edges
+    ([7, 0, 0, 100, 1, 0, 19], 5, 32),
+])
+def test_make_work_items_visit_each_block_in_one_run(sizes, n_tiles, bl):
+    """A compiled TPU grid writes an output block back when its index
+    changes and never reads it back: every tile and every expert must be
+    visited in ONE run of consecutive items, opened by its first/efirst
+    flag, and the items' row ranges must cover each expert's rows once."""
+    E = len(sizes)
+    off = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int32)
+    tile, expert, lo, hi, first, efirst = (np.asarray(a) for a in
+                                           make_work_items(jnp.asarray(off),
+                                                           n_tiles, bl, E))
+    assert tile.shape == (n_tiles + E,)
+    assert (np.diff(tile) >= 0).all() and (np.diff(expert) >= 0).all()
+    assert sorted(tile[first == 1]) == list(range(n_tiles))
+    assert sorted(expert[efirst == 1]) == list(range(E))
+    np.testing.assert_array_equal(first[1:], np.diff(tile) != 0)
+    np.testing.assert_array_equal(efirst[1:], np.diff(expert) != 0)
+    covered = np.zeros(n_tiles * bl, np.int32)
+    for t, e, a, b in zip(tile, expert, lo, hi):
+        rows = t * bl + np.arange(a, b)
+        assert ((rows >= off[e]) & (rows < off[e + 1])).all()
+        covered[rows] += 1
+    np.testing.assert_array_equal(covered[:off[-1]], 1)
+    np.testing.assert_array_equal(covered[off[-1]:], 0)
 
 
 def test_kernels_all_empty_dispatch_produce_zeros():

@@ -363,3 +363,29 @@ def test_serving_gate_failures_pairing():
                serving_gate_failures(fam(0, 16, 16, 100, 200, amis=3)))
     assert any("incomplete" in f for f in
                serving_gate_failures(fam(0, 16, 16, 100, 200)[:2]))
+
+
+def test_decode_inputs_do_not_alias_host_state(params):
+    """Regression: the decode step was handed ``jnp.asarray`` views of the
+    scheduler's lengths / gen_count arrays, which it then mutated before the
+    step had run — zero-copy on the CPU, so tokens depended on timing.
+    Every host array a decode step receives must keep the value it had at
+    dispatch."""
+    eng = ServeEngine(CFG, params, batch_slots=4, capacity=32, page_size=8)
+    seen = []
+    make = eng._decode_for
+
+    def recording(name):
+        fn = make(name)
+
+        def call(p, c, tok, *host):
+            seen.append([(a, np.array(a)) for a in host])
+            return fn(p, c, tok, *host)
+        return call
+
+    eng._decode_for = recording
+    eng.generate(_reqs(_mixed_prompts(CFG.vocab_size), max_new=6))
+    assert seen
+    for step in seen:
+        for arr, at_dispatch in step:
+            np.testing.assert_array_equal(np.asarray(arr), at_dispatch)

@@ -10,8 +10,8 @@ import pytest
 
 from repro import roofline
 from repro.configs import get_config
-from repro.launch.mesh import (DCN_BW, ICI_BW_PER_LINK, axis_bandwidth,
-                               make_debug_mesh, make_node_mesh)
+from repro.launch.mesh import make_debug_mesh, make_node_mesh
+from repro.roofline import DCN_BW, ICI_BW_PER_LINK, axis_bandwidth
 
 BASE = get_config("mixtral_8x7b").reduced().replace(
     num_layers=2, d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
@@ -66,7 +66,6 @@ def test_collective_stats_parses_compiled_hlo_this_pin():
     """The regex must keep matching whatever HLO text the *installed* jax
     pin emits (CI runs this on both pins): compile a psum and an all_to_all
     under shard_map and assert their bytes are extracted."""
-    from repro.compat import shard_map
     mesh = make_debug_mesh(1, 8)
 
     def body(x):
@@ -77,8 +76,9 @@ def test_collective_stats_parses_compiled_hlo_this_pin():
 
     x = jnp.zeros((8 * 8, 16), jnp.float32)
     from jax.sharding import PartitionSpec as P
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("model"),),
-                          out_specs=(P("model"), P("model")), check=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("model"),),
+                              out_specs=(P("model"), P("model")),
+                              check_vma=False))
     hlo = f.lower(x).compile().as_text()
     s = roofline.collective_stats(hlo)
     assert s["counts"]["all-reduce"] >= 1, hlo[:2000]
@@ -222,3 +222,14 @@ def test_chunked_model_never_slower_than_unchunked():
         ch = _modes(roofline.select_moe_parallel(
             BASE.replace(moe_a2a_chunks=4), mesh, L))["ep_a2a"]
         assert ch.t_total_s <= un.t_total_s + 1e-12
+
+
+def test_hardware_peaks_keyed_by_device_kind():
+    from repro import hardware
+    v5e = hardware.peaks("TPU v5 lite")
+    assert v5e.bf16_flops == 197e12 and v5e.hbm_bw == 819e9
+    assert v5e.source
+    assert roofline.PEAK_FLOPS_BF16 == v5e.bf16_flops
+    assert ICI_BW_PER_LINK == v5e.ici_bw / v5e.ici_links
+    with pytest.raises(KeyError, match="no hardware peaks"):
+        hardware.peaks("TPU v9 imaginary")

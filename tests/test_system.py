@@ -30,7 +30,7 @@ def test_paper_conf_registry():
 def test_checkpoint_policy_memory_ordering():
     """More aggressive policies save fewer residual bytes:
     none <= paper_min <= paper <= full."""
-    from repro.compat import saved_residual_nbytes
+    from repro.core.checkpoint import saved_residual_nbytes
     from repro.core.checkpoint import FFN_A, FFN_B, FFN_YSWI, tag
 
     L, d, h = 256, 64, 128

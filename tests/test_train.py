@@ -144,3 +144,21 @@ def test_data_pipeline_deterministic_and_packed():
     np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
     assert b1["tokens"].shape == (2, 32)
     assert b1["tokens"].max() < 64
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The entry points' compile cache: a ``JAX_COMPILATION_CACHE_DIR``
+    setting stands untouched; without it the cache goes to the fixed
+    ``.jax_cache/`` at the repository root."""
+    from repro.launch import cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cache.use_compile_cache() == str(tmp_path)
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = cache.use_compile_cache()
+    assert path == str(cache.REPO_ROOT / ".jax_cache")
+    assert (cache.REPO_ROOT / "chip_smoke.py").is_file()
+    assert updates == [("jax_compilation_cache_dir", path)]
