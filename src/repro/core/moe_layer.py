@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from repro.core.checkpoint import MOE_RESIDUAL_MODES
 from repro.core.gmm_backend import ResolvedBackend, gmm, gmm_dw, resolve
 from repro.core.routing import Dispatch
+from repro.obs import scoped
 
 __all__ = ["moe_ffn_blaze", "gmm", "gmm_dw"]
 
@@ -71,11 +72,30 @@ _ACTS = {
 }
 
 
+@scoped("combine")
 def _gate_per_slot(gates: jax.Array, token_index_map: jax.Array,
                    num_slots: int) -> jax.Array:
     """Scatter the (L, k) gate weights into expert-order slots (L*k,)."""
     return jnp.zeros((num_slots,), gates.dtype).at[
         token_index_map.reshape(-1)].set(gates.reshape(-1))
+
+
+@scoped("combine")
+def _combine(p_out, gates, tim):
+    """Gather each token's k partials (L*k, d) through ``token_index_map``
+    and contract them with its gates."""
+    L, k = tim.shape
+    parts = jnp.take(p_out, tim.reshape(-1), axis=0).reshape(L, k, -1)
+    return jnp.einsum("lk,lkd->ld", gates.astype(parts.dtype), parts)
+
+
+@scoped("combine")
+def _combine_dgates(act, dyu, tim, gates):
+    """The gates' gradient: each slot's activation against its output
+    gradient, gathered back to token order."""
+    dgates_slot = jnp.sum(act * dyu, axis=-1)          # (L*k,)
+    dgates = jnp.take(dgates_slot, tim.reshape(-1)).reshape(gates.shape)
+    return dgates.astype(gates.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -103,9 +123,7 @@ def _moe_swiglu_fwd(residuals, backend, x, w1, w2, w3, gates,
     y_swi = _silu(a) * b                               # (L*k, h)
     g_slot = _gate_per_slot(gates, tim, L * k)
     p_out = gmm(y_swi, w3, lens, backend=backend)      # (L*k, d) partials
-    # Combine: gather each token's k partials and contract with its gates.
-    parts = jnp.take(p_out, tim.reshape(-1), axis=0).reshape(L, k, -1)
-    y = jnp.einsum("lk,lkd->ld", gates.astype(parts.dtype), parts)
+    y = _combine(p_out, gates, tim)
     save_ab = residuals != "x"
     res = (x, w1, w2, w3, gates, eti, tim, lens, g_slot,
            a if save_ab else None, b if save_ab else None,
@@ -127,14 +145,13 @@ def _moe_swiglu_bwd(residuals, backend, res, dy):
         y_swi = _silu(a) * b                           # beyond-paper recompute
     # 1. Expert-summation backward: expand (L, d) grads to the slots via the
     #    index metadata (paper §3.2 step 1) — gather, no materialized buffer.
-    dyg = jnp.take(dy, eti, axis=0)                    # (L*k, d), unscaled
+    with jax.named_scope("combine"):
+        dyg = jnp.take(dy, eti, axis=0)                # (L*k, d), unscaled
     # 2. Final-projection grads (Algorithm 1 lines 18-20).
     dw3 = gmm_dw(y_swi * g_slot[:, None].astype(y_swi.dtype), dyg, lens,
                  backend=backend)
     dyu = gmm(dyg, jnp.swapaxes(w3, 1, 2), lens, backend=backend)
-    dgates_slot = jnp.sum(y_swi * dyu, axis=-1)        # (L*k,)
-    dgates = jnp.take(dgates_slot, tim.reshape(-1)).reshape(gates.shape)
-    dgates = dgates.astype(gates.dtype)
+    dgates = _combine_dgates(y_swi, dyu, tim, gates)
     dy_swi = dyu * g_slot[:, None].astype(dyu.dtype)
     # 3. SwiGLU backward with SiLU *recomputed* (Algorithm 1 lines 23-28).
     da = dy_swi * b * _dsilu(a)
@@ -175,8 +192,7 @@ def _moe_mlp_fwd(act, backend, residuals, x, w1, w3, gates,
     a = gmm(xg, w1, lens, backend=backend)
     g_slot = _gate_per_slot(gates, tim, L * k)
     p_out = gmm(f(a), w3, lens, backend=backend)
-    parts = jnp.take(p_out, tim.reshape(-1), axis=0).reshape(L, k, -1)
-    y = jnp.einsum("lk,lkd->ld", gates.astype(parts.dtype), parts)
+    y = _combine(p_out, gates, tim)
     # Smart checkpoint: save only the GEMM output `a` (or, under a
     # moe:recompute=ffn_a plan, not even that); act(a) is always recomputed.
     return y, (x, w1, w3, gates, eti, tim, lens, g_slot,
@@ -190,13 +206,12 @@ def _moe_mlp_bwd(act, backend, residuals, res, dy):
     if a is None:                   # "x": replay the first-layer grouped GEMM
         a = gmm(jnp.take(x, eti, axis=0), w1, lens, backend=backend)
     fa = f(a)                                          # recompute (paper §5.2)
-    dyg = jnp.take(dy, eti, axis=0)
+    with jax.named_scope("combine"):
+        dyg = jnp.take(dy, eti, axis=0)
     dw3 = gmm_dw(fa * g_slot[:, None].astype(fa.dtype), dyg, lens,
                  backend=backend)
     dyu = gmm(dyg, jnp.swapaxes(w3, 1, 2), lens, backend=backend)
-    dgates_slot = jnp.sum(fa * dyu, axis=-1)
-    dgates = jnp.take(dgates_slot, tim.reshape(-1)).reshape(gates.shape)
-    dgates = dgates.astype(gates.dtype)
+    dgates = _combine_dgates(fa, dyu, tim, gates)
     da = dyu * g_slot[:, None].astype(dyu.dtype) * df(a)
     xg = jnp.take(x, eti, axis=0)
     dw1 = gmm_dw(xg, da, lens, backend=backend)

@@ -33,6 +33,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scoped
+
 
 class Dispatch(NamedTuple):
     """The MoEBlaze routing metadata (paper Fig. 2)."""
@@ -55,6 +57,7 @@ class GatingOut(NamedTuple):
     logits: jax.Array        # (L, E) float — for z-loss
 
 
+@scoped("router")
 def top_k_gating(x: jax.Array, w_gate: jax.Array, k: int,
                  *, renormalize: bool = True) -> GatingOut:
     """``TopK(softmax(W_g x))`` (paper §2.1).
@@ -87,6 +90,7 @@ def router_z_loss(logits: jax.Array) -> jax.Array:
     return jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
 
 
+@scoped("dispatch")
 def build_dispatch(topk_experts: jax.Array, num_experts: int) -> Dispatch:
     """Sort-free dispatch-structure construction (paper §4.2, TPU rendering).
 
@@ -128,6 +132,7 @@ def build_dispatch(topk_experts: jax.Array, num_experts: int) -> Dispatch:
     )
 
 
+@scoped("dispatch")
 def slice_dispatch(d: Dispatch, e_lo, e_hi, *,
                    count: int | None = None) -> Dispatch:
     """Compact a global :class:`Dispatch` to the expert range ``[e_lo, e_hi)``

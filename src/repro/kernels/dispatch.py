@@ -30,6 +30,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import kernels
 from repro.core.routing import Dispatch
+from repro.obs import scoped
 
 
 def _onehot(tei_ref, num_experts: int, bl: int):
@@ -67,6 +68,7 @@ def _route_kernel(tei_ref, off_ref, dest_ref, counters,
     counters[...] = cnt + onehot.sum(axis=0, keepdims=True).astype(jnp.int32)
 
 
+@scoped("dispatch")
 @functools.partial(jax.jit, static_argnames=("num_experts", "k", "bl"))
 def build_dispatch_pallas(topk_experts: jax.Array, num_experts: int,
                           *, k: int | None = None, bl: int = 256) -> Dispatch:

@@ -49,6 +49,7 @@ from repro.core.baseline import moe_ffn_dense, moe_ffn_megablocks
 from repro.core.checkpoint import MOE_GATES, moe_residual_mode, tag
 from repro.core.moe_layer import moe_ffn_blaze
 from repro.models.common import dense_init
+from repro.obs import scoped
 
 MOE_PARALLEL_MODES = ("auto", "ep", "ep_a2a", "ep_a2a_hier", "tp")
 
@@ -126,6 +127,7 @@ def resolve_moe_parallel_ex(cfg, mesh, n_tokens: int | None = None):
     return decision
 
 
+@scoped("router")
 def _aux_of(g, cfg):
     return (cfg.aux_loss_weight *
             routing.load_balance_loss(g.router_probs, g.topk_experts,
@@ -133,6 +135,7 @@ def _aux_of(g, cfg):
             + cfg.z_loss_weight * routing.router_z_loss(g.logits))
 
 
+@scoped("experts")
 def _moe_dispatch(xf: jax.Array, p: dict, cfg, g, disp, rb, *,
                   sliced: bool = False):
     """The shared Dispatch-driven expert compute: gate tagging + the chosen
@@ -328,6 +331,7 @@ def _a2a_unpack(back, buf_idx, valid, n_rows: int):
     return jnp.where(valid[:, None], parts, jnp.zeros((), back.dtype))
 
 
+@scoped("experts")
 def _local_expert_ffn(rx, rg, re, E_loc: int, p: dict, cfg, rb):
     """Run received k=1 slots against the local expert bank: build over
     ``E_loc + 1`` experts (the extra one collects pads/overflow) and slice

@@ -21,6 +21,7 @@ from repro.models.attention import (attention_sublayer, init_attn_params,
 from repro.models.common import dense_init, rms_norm, softcap
 from repro.models.ffn import ffn_sublayer, init_ffn_params
 from repro.models.moe_block import init_moe_params, moe_sublayer
+from repro.obs import scoped
 
 ATTN_KINDS = {"attn_ffn", "attn_local_ffn", "attn_moe", "attn_local_moe"}
 
@@ -101,27 +102,30 @@ def _apply_sublayer(x, p, kind: str, cfg, *, mesh, positions, cache,
     stats = jnp.zeros((), jnp.float32)
     if kind in ATTN_KINDS:
         is_local = "local" in kind and cfg.sliding_window > 0
-        h = rms_norm(x, p["ln1"])
-        if paged is not None:
-            h, new_kv = paged_attention_sublayer(
-                h, p["attn"], cfg, is_local=is_local, positions=positions,
-                pages=cache[0], page_table=paged.page_table,
-                prefill=paged.prefill, offsets=paged.offsets,
-                attn_impl=paged.attn_impl)
-        else:
-            h, new_kv = attention_sublayer(
-                h, p["attn"], cfg, is_local=is_local, positions=positions,
-                cache=cache[0] if cache is not None else None)
-        if cfg.post_norms:
-            h = rms_norm(h, p["ln1_post"])
-        x = x + h
-        h = rms_norm(x, p["ln2"])
+        with jax.named_scope("attention"):
+            h = rms_norm(x, p["ln1"])
+            if paged is not None:
+                h, new_kv = paged_attention_sublayer(
+                    h, p["attn"], cfg, is_local=is_local,
+                    positions=positions, pages=cache[0],
+                    page_table=paged.page_table, prefill=paged.prefill,
+                    offsets=paged.offsets, attn_impl=paged.attn_impl)
+            else:
+                h, new_kv = attention_sublayer(
+                    h, p["attn"], cfg, is_local=is_local,
+                    positions=positions,
+                    cache=cache[0] if cache is not None else None)
+            if cfg.post_norms:
+                h = rms_norm(h, p["ln1_post"])
+            x = x + h
         if kind.endswith("moe"):
+            with jax.named_scope("router"):
+                h = rms_norm(x, p["ln2"])
             h, aux, mstats = moe_sublayer(h, p["moe"], cfg, mesh=mesh,
                                           with_stats=True)
             stats = mstats["a2a_overflow"]
         else:
-            h = ffn_sublayer(h, p["ffn"], cfg)
+            h = ffn_sublayer(rms_norm(x, p["ln2"]), p["ffn"], cfg)
         if cfg.post_norms:
             h = rms_norm(h, p["ln2_post"])
         return x + h, aux, stats, (new_kv,)
@@ -183,6 +187,7 @@ def _apply_group(x, gp, cfg, *, mesh, positions, cache_group,
 # ---------------------------------------------------------------------------
 
 
+@scoped("embed")
 def _embed_inputs(params, batch, cfg):
     dt = jnp.dtype(cfg.dtype)
     if cfg.input_kind == "tokens":
@@ -257,11 +262,12 @@ def forward(params, batch, cfg, *, mesh=None, last_only: bool = False,
             gp = jax.tree.map(lambda l: l[i], params["layers"])
             (x, aux, ov), _ = group_fn((x, aux, ov), gp)
 
-    if last_only:
-        x = x[:, -1:]
-    x = rms_norm(x, params["final_norm"])
-    logits = x @ params["unembed"].astype(x.dtype)
-    logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    with jax.named_scope("unembed_loss"):
+        if last_only:
+            x = x[:, -1:]
+        x = rms_norm(x, params["final_norm"])
+        logits = x @ params["unembed"].astype(x.dtype)
+        logits = softcap(logits.astype(jnp.float32), cfg.final_softcap)
     if with_stats:
         return logits, aux, {"moe_overflow": ov}
     return logits, aux
@@ -486,17 +492,18 @@ def paged_decode_step(params, cache, tokens, lengths, page_table, cfg, *,
 def train_loss(params, batch, cfg, *, mesh=None):
     logits, aux, stats = forward(params, batch, cfg, mesh=mesh,
                                  with_stats=True)
-    labels = batch["labels"]
-    if cfg.input_kind == "mixed":
-        # image positions carry no next-token loss
-        n_img = batch["image_embeds"].shape[1]
-        logits = logits[:, n_img:]
-    if cfg.causal:
-        logits = logits[:, :-1]
-        labels = labels[:, 1:]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    return loss + aux, {"ce": loss, "aux": aux,
-                        "moe_overflow": stats["moe_overflow"]}
+    with jax.named_scope("unembed_loss"):
+        labels = batch["labels"]
+        if cfg.input_kind == "mixed":
+            # image positions carry no next-token loss
+            n_img = batch["image_embeds"].shape[1]
+            logits = logits[:, n_img:]
+        if cfg.causal:
+            logits = logits[:, :-1]
+            labels = labels[:, 1:]
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        loss = (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        return loss + aux, {"ce": loss, "aux": aux,
+                            "moe_overflow": stats["moe_overflow"]}

@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import checkpoint as CK
 from repro.core import gmm_backend as GB
 from repro.core import memsim
@@ -133,13 +134,14 @@ def make_train_step(cfg, tcfg, *, mesh=None, backend=None,
         # a trace-time no-op once compiled).
         with GB.use_backend(resolved.name):
             (loss, metrics), grads = accumulate(params, batch)
-            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-            lr = cosine_schedule(
-                opt_state.step, peak_lr=tcfg.learning_rate,
-                warmup=tcfg.warmup_steps, total=tcfg.total_steps)
-            params, opt_state = adamw_update(
-                grads, opt_state, params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
-                eps=tcfg.eps, weight_decay=tcfg.weight_decay)
+            with jax.named_scope("optimizer"):
+                grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+                lr = cosine_schedule(
+                    opt_state.step, peak_lr=tcfg.learning_rate,
+                    warmup=tcfg.warmup_steps, total=tcfg.total_steps)
+                params, opt_state = adamw_update(
+                    grads, opt_state, params, lr=lr, b1=tcfg.b1, b2=tcfg.b2,
+                    eps=tcfg.eps, weight_decay=tcfg.weight_decay)
             metrics = dict(metrics, loss=loss, grad_norm=gnorm, lr=lr)
             return params, opt_state, metrics
 
@@ -208,7 +210,16 @@ def train(cfg, tcfg, *, mesh=None, params=None, log=print,
     The backend is re-resolved at the top of every step: entering a
     ``use_backend`` scope between steps (e.g. inside ``step_hook``) retargets
     the next step — jitted steps are cached per backend name, so flipping
-    back and forth does not recompile."""
+    back and forth does not recompile.
+
+    Each step runs in ``repro.obs`` host spans: ``train`` (a step trace
+    annotation) around ``train.next_batch``, ``train.to_device``,
+    ``train.resolve``, ``train.dispatch``, ``train.sync``, ``train.hook``,
+    ``train.log`` and ``train.checkpoint``; ``obs.note_step`` keeps each new
+    step function with its argument shapes.  A step hook is called from this
+    function's own frame, with ``params``, ``opt_state``, ``batch`` and
+    ``step_fn`` (the ``jax.jit`` object) among its locals: a benchmark reads
+    them there."""
     key = jax.random.PRNGKey(tcfg.seed)
     if params is None:
         params = T.init_params(key, cfg)
@@ -230,37 +241,53 @@ def train(cfg, tcfg, *, mesh=None, params=None, log=print,
             cfg.vocab_size, tcfg.seq_len, tcfg.batch_size, tcfg.seed)
 
     history = []
+    noted = None
     t0 = time.perf_counter()
     for step in range(tcfg.total_steps):
-        batch = {k: jnp.asarray(v) for k, v in next(batch_iterator).items()}
-        resolved = GB.resolve(None, config=_config_backend(cfg, tcfg))
-        step_fn = step_fn_for(resolved.name)
-        ts = time.perf_counter()
-        # (No scope needed here: the backend is pinned at the arg slot via
-        # make_train_step(backend=...) and again inside step_fn's own
-        # trace-time use_backend scope.)
-        params, opt_state, metrics = step_fn(params, opt_state, batch)
-        if step_hook is not None:
-            jax.block_until_ready(metrics)
-            metrics = dict(metrics, step_s=time.perf_counter() - ts,
-                           gmm_backend=resolved.name,
-                           remat_plan=resolved_plan.spec,
-                           peak_sim_bytes=peak_sim_bytes)
-            step_hook(step, metrics)
-        if step % tcfg.log_every == 0 or step == tcfg.total_steps - 1:
-            m = {k: float(v) for k, v in metrics.items()
-                 if not isinstance(v, str)}
-            m["step"] = step
-            m.setdefault("step_s", time.perf_counter() - ts)
-            m["wall_s"] = time.perf_counter() - t0
-            m["gmm_backend"] = resolved.name
-            m["remat_plan"] = resolved_plan.spec
-            m["peak_sim_bytes"] = peak_sim_bytes
-            history.append(m)
-            log(f"step {step:5d} loss {m['loss']:.4f} ce {m['ce']:.4f} "
-                f"gnorm {m['grad_norm']:.3f} lr {m['lr']:.2e} "
-                f"({m['wall_s']:.1f}s)")
-        if tcfg.checkpoint_every and step and step % tcfg.checkpoint_every == 0:
-            checkpointing.save_checkpoint(
-                f"{tcfg.checkpoint_dir}/step_{step}", step, params, opt_state)
+        with obs.span("train", step_num=step):
+            with obs.span("train.next_batch"):
+                host_batch = next(batch_iterator)
+            with obs.span("train.to_device"):
+                batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+            with obs.span("train.resolve"):
+                resolved = GB.resolve(None, config=_config_backend(cfg, tcfg))
+                step_fn = step_fn_for(resolved.name)
+            if step_fn is not noted:
+                obs.note_step(step_fn, (params, opt_state, batch))
+                noted = step_fn
+            ts = time.perf_counter()
+            # (No scope needed here: the backend is pinned at the arg slot via
+            # make_train_step(backend=...) and again inside step_fn's own
+            # trace-time use_backend scope.)
+            with obs.span("train.dispatch"):
+                params, opt_state, metrics = step_fn(params, opt_state, batch)
+            if step_hook is not None:
+                with obs.span("train.sync"):
+                    jax.block_until_ready(metrics)
+                metrics = dict(metrics, step_s=time.perf_counter() - ts,
+                               gmm_backend=resolved.name,
+                               remat_plan=resolved_plan.spec,
+                               peak_sim_bytes=peak_sim_bytes)
+                with obs.span("train.hook"):
+                    step_hook(step, metrics)
+            if step % tcfg.log_every == 0 or step == tcfg.total_steps - 1:
+                with obs.span("train.log"):
+                    m = {k: float(v) for k, v in metrics.items()
+                         if not isinstance(v, str)}
+                    m["step"] = step
+                    m.setdefault("step_s", time.perf_counter() - ts)
+                    m["wall_s"] = time.perf_counter() - t0
+                    m["gmm_backend"] = resolved.name
+                    m["remat_plan"] = resolved_plan.spec
+                    m["peak_sim_bytes"] = peak_sim_bytes
+                    history.append(m)
+                    log(f"step {step:5d} loss {m['loss']:.4f} "
+                        f"ce {m['ce']:.4f} gnorm {m['grad_norm']:.3f} "
+                        f"lr {m['lr']:.2e} ({m['wall_s']:.1f}s)")
+            if (tcfg.checkpoint_every and step
+                    and step % tcfg.checkpoint_every == 0):
+                with obs.span("train.checkpoint"):
+                    checkpointing.save_checkpoint(
+                        f"{tcfg.checkpoint_dir}/step_{step}", step, params,
+                        opt_state)
     return params, opt_state, history
