@@ -8,8 +8,16 @@ Every grouped GEMM in the MoEBlaze core funnels through two primitives:
   * ``gmm_dw(lhs, dout, group_sizes)``— (S, d), (S, h) -> (E, d, h), the
     per-group weight gradient (contract the grouped row axis).
 
-Both accumulate in fp32 and return ``lhs.dtype``.  The registry makes the
-primitive swappable (MegaBlocks-style):
+Both accumulate in fp32 and return ``lhs.dtype``.  The ``ragged`` backend
+hands the GEMM bfloat16 copies of float32 operands wherever the product is one
+bfloat16 pass anyway: where the computation is compiled for a TPU
+(``jax.lax.platform_dependent``) and no higher
+``jax.default_matmul_precision`` than the default is in force at trace time.
+XLA's TPU kernel reads its operand tiles from HBM at the width it is given
+and rounds them itself, so rounding first halves the bytes and leaves the
+result as it was.  Compiled for the CPU, under a higher precision, and for
+operands already narrower than float32 nothing is rounded.  The registry
+makes the primitive swappable (MegaBlocks-style):
 
   * ``ragged``  — ``jax.lax.ragged_dot`` / ``ragged_dot_general``, the XLA
     grouped GEMM (on a TPU, XLA's own grouped-matmul kernel).  The auto
@@ -80,33 +88,53 @@ def _offsets_of(group_sizes: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-class RaggedBackend:
-    """``jax.lax.ragged_dot[_general]`` — the XLA grouped-GEMM fast path."""
+#: ``jax_default_matmul_precision`` settings under which a TPU product of
+#: float32 operands is one bfloat16 pass (None is JAX's default).
+_ONE_BF16_PASS = (None, "default", "bfloat16", "BF16_BF16_F32")
 
-    name = "ragged"
 
-    @staticmethod
-    def gmm(lhs, rhs, group_sizes):
-        gs = group_sizes.astype(jnp.int32)
-        out = jax.lax.ragged_dot(lhs, rhs, gs,
-                                 preferred_element_type=jnp.float32)
-        # Rows past the group total belong to no group.  XLA's TPU kernel
-        # leaves them unwritten (whatever the buffer held, NaN included);
-        # the contract — and the dead zone of a sliced dispatch — needs
-        # zeros.
-        rows = jnp.arange(lhs.shape[0], dtype=jnp.int32)[:, None]
-        return jnp.where(rows < gs.sum(), out, 0).astype(lhs.dtype)
+def _by_platform(plain, rounded, *args):
+    """The one place the grouped GEMM looks at the platform: ``rounded``
+    where the computation is compiled for a TPU, ``plain`` elsewhere."""
+    return jax.lax.platform_dependent(*args, tpu=rounded, default=plain)
 
-    @staticmethod
-    def gmm_dw(lhs, dout, group_sizes):
-        dims = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),  # contract rows
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[])
-        out = jax.lax.ragged_dot_general(
-            lhs, dout, group_sizes.astype(jnp.int32), dims,
-            preferred_element_type=jnp.float32)
-        return out.astype(lhs.dtype)
+
+def _to_bf16(x):
+    return x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
+
+
+def _one_pass(product, lhs, rhs):
+    """``product(lhs, rhs)``, on bfloat16 copies of float32 operands where
+    the product is one bfloat16 pass anyway: compiled for a TPU, with no
+    higher default matmul precision in force at trace time."""
+    if (jnp.float32 not in (lhs.dtype, rhs.dtype)
+            or jax.config.jax_default_matmul_precision not in _ONE_BF16_PASS):
+        return product(lhs, rhs)
+    return _by_platform(product,
+                        lambda l, r: product(_to_bf16(l), _to_bf16(r)),
+                        lhs, rhs)
+
+
+def _ragged_gmm_impl(lhs, rhs, group_sizes):
+    gs = group_sizes.astype(jnp.int32)
+    out = _one_pass(lambda l, r: jax.lax.ragged_dot(
+        l, r, gs, preferred_element_type=jnp.float32), lhs, rhs)
+    # Rows past the group total belong to no group.  XLA's TPU kernel
+    # leaves them unwritten (whatever the buffer held, NaN included); the
+    # contract — and the dead zone of a sliced dispatch — needs zeros.
+    rows = jnp.arange(lhs.shape[0], dtype=jnp.int32)[:, None]
+    return jnp.where(rows < gs.sum(), out, 0).astype(lhs.dtype)
+
+
+def _ragged_dw_impl(lhs, dout, group_sizes):
+    gs = group_sizes.astype(jnp.int32)
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),  # contract rows
+        lhs_ragged_dimensions=[0],
+        rhs_group_dimensions=[])
+    out = _one_pass(lambda l, d: jax.lax.ragged_dot_general(
+        l, d, gs, dims, preferred_element_type=jnp.float32), lhs, dout)
+    return out.astype(lhs.dtype)
 
 
 class SegmentBackend:
@@ -171,49 +199,63 @@ def _pallas_dw_impl(lhs, dout, group_sizes):
     return gmm_dw_pallas(lhs, dout, _offsets_of(group_sizes))
 
 
-# ``pallas_call`` has no JVP rule, so the kernels are wrapped in custom VJPs
-# built from each other (the grouped GEMM is linear: d_lhs flows through the
-# transposed weights, d_rhs is exactly the grouped weight gradient).  This
-# keeps the backend contract uniform — every backend is differentiable by
-# plain autodiff, not just inside the MoE layer's hand-written VJP.
+# The grouped GEMM is linear: d_lhs flows through the transposed weights and
+# d_rhs is exactly the grouped weight gradient.  Each backend's pair of
+# kernels is wrapped in custom VJPs built from each other, which keeps the
+# backend contract uniform — every backend is differentiable by plain
+# autodiff, not just inside the MoE layer's hand-written VJP.  ``pallas_call``
+# has no JVP rule; ``ragged_dot``'s own rule would hand back bfloat16
+# cotangents for the rounded operands, where these keep float32.
 
 
-@jax.custom_vjp
-def _pallas_gmm(lhs, rhs, group_sizes):
-    return _pallas_gmm_impl(lhs, rhs, group_sizes)
+def _linear_pair(gmm_impl, dw_impl):
+    @jax.custom_vjp
+    def gmm_fn(lhs, rhs, group_sizes):
+        return gmm_impl(lhs, rhs, group_sizes)
+
+    def gmm_fwd(lhs, rhs, group_sizes):
+        return gmm_impl(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def gmm_bwd(res, dout):
+        lhs, rhs, gs = res
+        dlhs = gmm_impl(dout, jnp.swapaxes(rhs, 1, 2), gs)
+        drhs = dw_impl(lhs, dout, gs)
+        return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), None
+
+    @jax.custom_vjp
+    def dw_fn(lhs, dout, group_sizes):
+        return dw_impl(lhs, dout, group_sizes)
+
+    def dw_fwd(lhs, dout, group_sizes):
+        return dw_impl(lhs, dout, group_sizes), (lhs, dout, group_sizes)
+
+    def dw_bwd(res, ddw):
+        lhs, dout, gs = res
+        dlhs = gmm_impl(dout, jnp.swapaxes(ddw, 1, 2), gs)
+        ddout = gmm_impl(lhs, ddw, gs)
+        return dlhs.astype(lhs.dtype), ddout.astype(dout.dtype), None
+
+    gmm_fn.defvjp(gmm_fwd, gmm_bwd)
+    dw_fn.defvjp(dw_fwd, dw_bwd)
+    return gmm_fn, dw_fn
 
 
-def _pallas_gmm_fwd(lhs, rhs, group_sizes):
-    return _pallas_gmm_impl(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+_ragged_gmm, _ragged_dw = _linear_pair(_ragged_gmm_impl, _ragged_dw_impl)
+_pallas_gmm, _pallas_dw = _linear_pair(_pallas_gmm_impl, _pallas_dw_impl)
 
 
-def _pallas_gmm_bwd(res, dout):
-    lhs, rhs, gs = res
-    dlhs = _pallas_gmm_impl(dout, jnp.swapaxes(rhs, 1, 2), gs)
-    drhs = _pallas_dw_impl(lhs, dout, gs)
-    return dlhs.astype(lhs.dtype), drhs.astype(rhs.dtype), None
+class RaggedBackend:
+    """``jax.lax.ragged_dot[_general]`` — the XLA grouped-GEMM fast path."""
 
+    name = "ragged"
 
-_pallas_gmm.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
+    @staticmethod
+    def gmm(lhs, rhs, group_sizes):
+        return _ragged_gmm(lhs, rhs, group_sizes)
 
-
-@jax.custom_vjp
-def _pallas_dw(lhs, dout, group_sizes):
-    return _pallas_dw_impl(lhs, dout, group_sizes)
-
-
-def _pallas_dw_fwd(lhs, dout, group_sizes):
-    return _pallas_dw_impl(lhs, dout, group_sizes), (lhs, dout, group_sizes)
-
-
-def _pallas_dw_bwd(res, ddw):
-    lhs, dout, gs = res
-    dlhs = _pallas_gmm_impl(dout, jnp.swapaxes(ddw, 1, 2), gs)
-    ddout = _pallas_gmm_impl(lhs, ddw, gs)
-    return dlhs.astype(lhs.dtype), ddout.astype(dout.dtype), None
-
-
-_pallas_dw.defvjp(_pallas_dw_fwd, _pallas_dw_bwd)
+    @staticmethod
+    def gmm_dw(lhs, dout, group_sizes):
+        return _ragged_dw(lhs, dout, group_sizes)
 
 
 class PallasBackend:

@@ -147,21 +147,29 @@ def _moe_swiglu_bwd(residuals, backend, res, dy):
     #    index metadata (paper §3.2 step 1) — gather, no materialized buffer.
     with jax.named_scope("combine"):
         dyg = jnp.take(dy, eti, axis=0)                # (L*k, d), unscaled
+    # The backward is staged so that one slot-sized GEMM operand is live at
+    # a time, whether the backend rounds it to a new (bfloat16) buffer or
+    # reads it in place: the final projection's weight gradient before the
+    # output-gradient GEMM, and A's branch before B's is formed.  The
+    # barriers only order; the values are unchanged.
     # 2. Final-projection grads (Algorithm 1 lines 18-20).
     dw3 = gmm_dw(y_swi * g_slot[:, None].astype(y_swi.dtype), dyg, lens,
                  backend=backend)
+    dw3, w3 = jax.lax.optimization_barrier((dw3, w3))
     dyu = gmm(dyg, jnp.swapaxes(w3, 1, 2), lens, backend=backend)
     dgates = _combine_dgates(y_swi, dyu, tim, gates)
     dy_swi = dyu * g_slot[:, None].astype(dyu.dtype)
-    # 3. SwiGLU backward with SiLU *recomputed* (Algorithm 1 lines 23-28).
-    da = dy_swi * b * _dsilu(a)
-    db = dy_swi * _silu(a)
-    # 4. First-layer grads; the routed-token gather is recomputed, not saved.
+    # 3-4. SwiGLU backward with SiLU *recomputed* (Algorithm 1 lines 23-28)
+    #      and the first-layer grads; the routed-token gather is recomputed,
+    #      not saved.
     xg = jnp.take(x, eti, axis=0)
+    da = dy_swi * b * _dsilu(a)
     dw1 = gmm_dw(xg, da, lens, backend=backend)
+    dxg = gmm(da, jnp.swapaxes(w1, 1, 2), lens, backend=backend)
+    dw1, dxg, a = jax.lax.optimization_barrier((dw1, dxg, a))
+    db = dy_swi * _silu(a)
     dw2 = gmm_dw(xg, db, lens, backend=backend)
-    dxg = gmm(da, jnp.swapaxes(w1, 1, 2), lens, backend=backend) + \
-        gmm(db, jnp.swapaxes(w2, 1, 2), lens, backend=backend)
+    dxg = dxg + gmm(db, jnp.swapaxes(w2, 1, 2), lens, backend=backend)
     # 5. Token-gradient accumulation (paper §3.2 step 3).
     dx = jnp.zeros_like(x).at[eti].add(dxg.astype(x.dtype))
     return dx, dw1, dw2, dw3, dgates, None, None, None, None
@@ -208,8 +216,11 @@ def _moe_mlp_bwd(act, backend, residuals, res, dy):
     fa = f(a)                                          # recompute (paper §5.2)
     with jax.named_scope("combine"):
         dyg = jnp.take(dy, eti, axis=0)
+    # Staged as the SwiGLU backward's first step: the final projection's
+    # weight gradient before the output-gradient GEMM.
     dw3 = gmm_dw(fa * g_slot[:, None].astype(fa.dtype), dyg, lens,
                  backend=backend)
+    dw3, w3 = jax.lax.optimization_barrier((dw3, w3))
     dyu = gmm(dyg, jnp.swapaxes(w3, 1, 2), lens, backend=backend)
     dgates = _combine_dgates(fa, dyu, tim, gates)
     da = dyu * g_slot[:, None].astype(dyu.dtype) * df(a)

@@ -250,7 +250,10 @@ def forward(params, batch, cfg, *, mesh=None, last_only: bool = False,
         return (_act_constraint(x, mesh), aux + a, ov + o), None
 
     if mode == "group":
-        group_fn = jax.checkpoint(group_fn, policy=payload, prevent_cse=False)
+        # prevent_cse: a one-group scan is unrolled, and XLA would otherwise
+        # share values between the forward and the recompute, keeping them
+        # (the GEMMs' bfloat16 weight copies among them) live across the loss.
+        group_fn = jax.checkpoint(group_fn, policy=payload, prevent_cse=True)
 
     zero = jnp.zeros((), jnp.float32)
     if cfg.scan_layers:

@@ -4,6 +4,8 @@
 across activations and empty-expert group shapes; plus selection semantics.
 (The fused layer path gets its dedicated matrix in test_fused_path.py.)"""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -280,3 +282,153 @@ def test_env_var_reaches_moe_layer(monkeypatch):
     monkeypatch.delenv(GB.ENV_VAR)
     y_exp = moe_ffn_blaze(x, gates, disp, w1, w3, w2, backend="segment")
     np.testing.assert_array_equal(np.asarray(y_env), np.asarray(y_exp))
+
+
+# ---------------------------------------------------------------------------
+# bfloat16 operands where the product is one bfloat16 pass (``ragged``)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the one function that looks at the platform: the ``ragged``
+    backend then rounds as it does compiled for a TPU, here on the CPU."""
+    monkeypatch.setattr(GB, "_by_platform",
+                        lambda plain, rounded, *args: rounded(*args))
+
+
+def _rounded(*ts):
+    return tuple(t.astype(jnp.bfloat16).astype(jnp.float32) for t in ts)
+
+
+def _gemm_operand_dtypes(fn, *args):
+    """The operand dtypes of every grouped GEMM ``fn`` traces to."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("ragged_dot"):
+                found.append(tuple(str(v.aval.dtype) for v in eqn.invars[:2]))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+                    elif hasattr(sub, "jaxpr"):
+                        walk(sub.jaxpr)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert found
+    return found
+
+
+@pytest.mark.parametrize("sizes", [None, (0, 20, 0, 12, 5)],
+                         ids=["balanced", "empty-mid"])
+def test_ragged_rounds_f32_operands_on_tpu(on_tpu, sizes):
+    """On a TPU at the default precision, ``ragged`` equals the exact oracle
+    run on the bfloat16-rounded operands, and still returns float32."""
+    lhs, rhs, dout, gs = _grouped(0, 37, 16, 24, 5, sizes)
+    y = GB.gmm(lhs, rhs, gs, backend="ragged")
+    dw = GB.gmm_dw(lhs, dout, gs, backend="ragged")
+    assert y.dtype == dw.dtype == jnp.float32
+    lr, rr, dr = _rounded(lhs, rhs, dout)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(
+        GB.gmm(lr, rr, gs, backend="segment")), rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(
+        GB.gmm_dw(lr, dr, gs, backend="segment")), rtol=1e-6, atol=1e-6)
+    # the rounding is real: the unrounded oracle differs at bfloat16 scale
+    assert not np.allclose(np.asarray(y), np.asarray(
+        GB.gmm(lhs, rhs, gs, backend="segment")), rtol=1e-5, atol=1e-5)
+    assert _gemm_operand_dtypes(
+        lambda a, b: GB.gmm(a, b, gs, backend="ragged"),
+        lhs, rhs) == [("bfloat16", "bfloat16")]
+
+
+@pytest.mark.parametrize("precision", ["highest", "float32"])
+def test_ragged_keeps_f32_under_higher_precision(on_tpu, monkeypatch,
+                                                 precision):
+    """A higher ``jax.default_matmul_precision`` in force at trace time
+    turns the rounding off: the result is bit-identical to the CPU path."""
+    lhs, rhs, dout, gs = _grouped(1, 37, 16, 24, 5)
+    with jax.default_matmul_precision(precision):
+        y = GB.gmm(lhs, rhs, gs, backend="ragged")
+        dw = GB.gmm_dw(lhs, dout, gs, backend="ragged")
+        assert _gemm_operand_dtypes(
+            lambda a, b: GB.gmm(a, b, gs, backend="ragged"),
+            lhs, rhs) == [("float32", "float32")]
+    monkeypatch.undo()                                  # the CPU path
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(
+        GB.gmm(lhs, rhs, gs, backend="ragged")))
+    np.testing.assert_array_equal(np.asarray(dw), np.asarray(
+        GB.gmm_dw(lhs, dout, gs, backend="ragged")))
+
+
+def test_ragged_passes_bf16_operands_through(on_tpu):
+    """Operands already narrower than float32 are not touched."""
+    lhs, rhs, dout, gs = (t.astype(jnp.bfloat16) if t.dtype == jnp.float32
+                          else t for t in _grouped(2, 37, 16, 24, 5))
+    assert _gemm_operand_dtypes(
+        lambda a, b: GB.gmm(a, b, gs, backend="ragged"),
+        lhs, rhs) == [("bfloat16", "bfloat16")]
+    y = GB.gmm(lhs, rhs, gs, backend="ragged")
+    dw = GB.gmm_dw(lhs, dout, gs, backend="ragged")
+    assert y.dtype == dw.dtype == jnp.bfloat16
+    ref = GB.gmm(lhs.astype(jnp.float32), rhs.astype(jnp.float32), gs,
+                 backend="segment")
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_ragged_rounding_keeps_f32_cotangents(on_tpu):
+    """Plain autodiff through the rounded GEMM: the gradients are float32,
+    each the grouped product of rounded operands (not a rounded result)."""
+    lhs, rhs, dout, gs = _grouped(3, 37, 16, 24, 5)
+
+    def f(l, r):
+        return (GB.gmm(l, r, gs, backend="ragged") * dout).sum()
+
+    dl, dr = jax.grad(f, argnums=(0, 1))(lhs, rhs)
+    assert dl.dtype == dr.dtype == jnp.float32
+    lr, rr, dor = _rounded(lhs, rhs, dout)
+    np.testing.assert_allclose(np.asarray(dl), np.asarray(GB.gmm(
+        dor, jnp.swapaxes(rr, 1, 2), gs, backend="segment")),
+        rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(dr), np.asarray(GB.gmm_dw(
+        lr, dor, gs, backend="segment")), rtol=1e-6, atol=1e-6)
+
+
+def test_moe_vjp_with_rounded_operands(on_tpu):
+    """The MoE layer's staged backward on rounded operands gives every
+    gradient, within bfloat16 rounding of the float32 oracle."""
+    L, d, h, E, k = 64, 16, 32, 4, 2
+    x, w1, w2, w3, gates, disp = _moe_setup(3, L, d, h, E, k)
+
+    def loss(be):
+        def f(x, w1, w2, w3, gates):
+            y = moe_ffn_blaze(x, gates, disp, w1, w3, w2, backend=be)
+            return (y ** 2).sum()
+        return f
+
+    args = (x, w1, w2, w3, gates)
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss("ragged"), argnums=(0, 1, 2, 3,
+                                                                  4)))(*args))
+    assert jaxpr.count("optimization_barrier") == 2      # the staged backward
+    g = jax.grad(loss("ragged"), argnums=(0, 1, 2, 3, 4))(*args)
+    gr = jax.grad(loss("segment"), argnums=(0, 1, 2, 3, 4))(*args)
+    for i, (a, b) in enumerate(zip(g, gr)):
+        assert a.dtype == jnp.float32
+        scale = np.abs(np.asarray(b)).max()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=3e-2 * scale, err_msg=f"argnum {i}")
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_ragged_rounds_where_compiled_for_tpu(platform):
+    """The rounding follows the platform the GEMM is lowered for, not the
+    process's default backend: bfloat16 copies of both operands for a TPU,
+    none for the CPU."""
+    lhs, rhs, _, gs = _grouped(4, 37, 16, 24, 5)
+    text = jax.jit(lambda a, b: GB.gmm(a, b, gs, backend="ragged")).trace(
+        lhs, rhs).lower(lowering_platforms=(platform,)).as_text()
+    rounded = re.findall(r"stablehlo.convert %arg\d : \(tensor<[\dx]+xf32>\)"
+                         r" -> tensor<[\dx]+xbf16>", text)
+    assert len(rounded) == (2 if platform == "tpu" else 0), text

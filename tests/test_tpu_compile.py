@@ -12,6 +12,8 @@ The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -148,10 +150,10 @@ def test_attention_kernels_compile(dtype, one_chip, compiled_kernels):
         _sds((B, pps), jnp.int32, one_chip), _sds((B,), jnp.int32, one_chip))
 
 
-def test_conf6_train_step_fits_one_chip(one_chip):
+def _conf6_train_step(one_chip):
     """One whole AdamW train step of paper conf6 (float32, auto backend) at
-    16 x 1024 tokens: the program the chip smoke run executes.  Its compiled
-    footprint must fit the chip's HBM."""
+    16 x 1024 tokens, compiled for one chip; with its footprint, argument +
+    output + temp - alias bytes (the benchmark's ``step_hbm_gib``)."""
     from repro.configs import get_config
     from repro.configs.base import TrainConfig
     from repro.models import transformer as T
@@ -161,6 +163,7 @@ def test_conf6_train_step_fits_one_chip(one_chip):
     cfg = get_config("paper_conf6")
     tcfg = TrainConfig(batch_size=16, seq_len=1024)
     step = make_train_step(cfg, tcfg)
+    assert step.resolved_backend.name == "ragged"
 
     def on_chip(tree):
         return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
@@ -172,7 +175,83 @@ def test_conf6_train_step_fits_one_chip(one_chip):
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt, {"tokens": tok, "labels": tok}).compile()
     mem = compiled.memory_analysis()
+    return compiled, (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                      + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+#: The parent program's compiled conf6 step, float32 GEMM operands: the most
+#: the step may take (``step_hbm_gib`` 9.871 GiB).
+CONF6_STEP_BYTES = 10_598_891_008
+#: The parent program's compiled MoE layer (GELU, gradient of every input) at
+#: conf6 width, float32 GEMM operands: the most that layer may take.
+MLP_LAYER_BYTES = 4_969_625_600
+
+
+@pytest.fixture(scope="module")
+def conf6_step(one_chip):
+    """The conf6 train step compiled for one chip, and its footprint."""
+    jax.clear_caches()
+    yield _conf6_train_step(one_chip)
+    jax.clear_caches()
+
+
+def test_conf6_train_step_fits_one_chip(conf6_step):
+    """The program the chip smoke run executes: its compiled footprint must
+    fit the chip's HBM."""
+    peak = conf6_step[1]
+    assert peak <= hardware.peaks().hbm_bytes, peak
+
+
+def _ragged_dot_operands(hlo: str) -> list[list[str]]:
+    """The operand types of each of XLA's TPU ragged-dot kernel calls."""
+    calls = []
+    for line in hlo.splitlines():      # the GEMMs, not their s32 metadata
+        if re.match(r"\s*%ragged-dot-\S* = f32\[", line) and \
+                'custom_call_target="tpu_custom_call"' in line:
+            ops = re.search(
+                r"operand_layout_constraints=\{(.*?)\}, frontend_attributes",
+                line)
+            calls.append([o.split("[")[0] for o in ops.group(1).split(", ")])
+    return calls
+
+
+def test_conf6_train_step_feeds_gmm_bf16(conf6_step):
+    """Compiled for the chip, the ``ragged`` backend hands every grouped GEMM
+    of the conf6 step bfloat16 operands (the rounding the kernel did on
+    float32 tiles anyway), and the step takes no more HBM than it did with
+    float32 operands."""
+    compiled, peak = conf6_step
+    calls = _ragged_dot_operands(compiled.as_text())
+    assert len(calls) == 11, calls        # 9 GEMMs a step + 2 recomputed
+    for ops in calls:
+        assert ops[-2:] == ["bf16", "bf16"], ops
+    assert peak <= CONF6_STEP_BYTES, peak
+
+
+def test_mlp_moe_layer_feeds_gmm_bf16(one_chip):
+    """The MoE layer with a one-matrix activation (GELU) at conf6 width:
+    its six grouped GEMMs take bfloat16 operands, and its gradient takes no
+    more HBM than it did with float32 operands."""
+    from repro.core.moe_layer import moe_ffn_blaze
+    from repro.core.routing import build_dispatch
+
+    def loss(x, w1, w3, gates, topk):
+        y = moe_ffn_blaze(x, gates, build_dispatch(topk, E), w1, w3,
+                          activation="gelu", backend="ragged")
+        return (y ** 2).sum()
+
+    f32 = jnp.float32
+    jax.clear_caches()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _sds((L, D), f32, one_chip), _sds((E, D, H), f32, one_chip),
+        _sds((E, H, D), f32, one_chip), _sds((L, K), f32, one_chip),
+        _sds((L, K), jnp.int32, one_chip)).compile()
+    jax.clear_caches()
+    calls = _ragged_dot_operands(compiled.as_text())
+    assert len(calls) == 6, calls
+    for ops in calls:
+        assert ops[-2:] == ["bf16", "bf16"], ops
+    mem = compiled.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert step.resolved_backend.name == "ragged"
-    assert peak <= hardware.peaks().hbm_bytes, peak
+    assert peak <= MLP_LAYER_BYTES, peak
